@@ -187,14 +187,15 @@ def split_scenario(quick: bool = False) -> dict:
 def run_split_subprocess(quick: bool) -> dict:
     """Re-exec this script with 8 forced host devices for the split
     scenario (the parent process is pinned to the real 1-CPU pool, which
-    cannot represent a 2-leg mesh)."""
+    cannot represent a 2-leg mesh). The child is a CPU simulation: it is
+    held to the CPU so that it never reaches for a chip the parent holds."""
     cmd = [sys.executable, __file__, "--split-only"]
     if quick:
         cmd.append("--quick")
     pythonpath = os.pathsep.join(
         p for p in (str(REPO_ROOT / "src"), os.environ.get("PYTHONPATH")) if p
     )
-    env = {**os.environ, "PYTHONPATH": pythonpath}
+    env = {**os.environ, "PYTHONPATH": pythonpath, "JAX_PLATFORMS": "cpu"}
     res = subprocess.run(
         cmd, capture_output=True, text=True, timeout=1200, env=env,
         cwd=str(REPO_ROOT),

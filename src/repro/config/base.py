@@ -120,6 +120,8 @@ class ModelConfig:
         ) * d
         if self.qkv_bias:
             attn += self.num_heads * hd + 2 * self.num_kv_heads * hd
+        if self.qk_norm:
+            attn += 2 * hd  # q and k RMSNorm scales
         if self.block == BlockKind.MOE:
             assert self.moe is not None
             n_mat = 3 if self.gated_mlp else 2

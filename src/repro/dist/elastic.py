@@ -9,9 +9,9 @@ memory.
 
 ``jax.device_put(x, sharding)`` performs the actual cross-mesh transfer;
 it resolves source and destination shardings and issues the minimal
-copies. A fallback path materializes through host RAM for backends or
-mesh pairs where the direct transfer is unsupported — correct everywhere,
-merely slower.
+copies device to device. A transfer it cannot make raises: a reshard
+never stages through host memory, which would change what a live
+reshard costs.
 """
 from __future__ import annotations
 
@@ -19,29 +19,20 @@ from typing import Any
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
-import numpy as np
 
 from repro.config.base import ShardingLayout
 from repro.dist.sharding import param_shardings
 
 
-def _put(x, sharding) -> jax.Array:
-    try:
-        return jax.device_put(x, sharding)
-    except (ValueError, RuntimeError):
-        # cross-mesh direct transfer unsupported: stage through host memory
-        return jax.device_put(np.asarray(x), sharding)
-
-
 def reshard_tree(tree: Any, shardings: Any) -> Any:
     """device_put every leaf of ``tree`` onto the matching sharding leaf."""
-    return jax.tree_util.tree_map(_put, tree, shardings)
+    return jax.tree_util.tree_map(jax.device_put, tree, shardings)
 
 
 def replicate(tree: Any, mesh) -> Any:
     """Fully replicate a pytree across every device of ``mesh``."""
     repl = NamedSharding(mesh, P())
-    return jax.tree_util.tree_map(lambda x: _put(x, repl), tree)
+    return jax.tree_util.tree_map(lambda x: jax.device_put(x, repl), tree)
 
 
 def reshard_params(params: Any, specs: Any, mesh, layout: ShardingLayout) -> Any:

@@ -30,6 +30,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro.launch.mesh import mesh_over
+
 
 def mesh_shape_for(n_devices: int) -> Tuple[int, int]:
     """Deterministic (data, model) factorization of ``n_devices``."""
@@ -95,8 +97,7 @@ class ElasticMeshManager:
         plan = self._plans.get(n)
         if plan is None:
             shape = mesh_shape_for(n)
-            devs = np.asarray(self.devices[:n], dtype=object).reshape(shape)
-            mesh = jax.sharding.Mesh(devs, ("data", "model"))
+            mesh = mesh_over(self.devices[:n], shape, ("data", "model"))
             plan = MeshPlan(
                 requested_devices=int(device_count),
                 device_count=n,
@@ -139,10 +140,7 @@ class ElasticMeshManager:
         plan = self._alloc_plans.get(key)
         if plan is None:
             shape = mesh_shape_for(honored_total)
-            devs = np.asarray(
-                self.devices[:honored_total], dtype=object
-            ).reshape(shape)
-            mesh = jax.sharding.Mesh(devs, ("data", "model"))
+            mesh = mesh_over(self.devices[:honored_total], shape, ("data", "model"))
             spans, lo = [], 0
             for s in shares:
                 spans.append((lo, lo + s))
@@ -237,27 +235,43 @@ def _overlap(a, b) -> int:
     return v
 
 
-def _leaf_moved_bytes(leaf, old_sharding, new_sharding) -> int:
-    """Bytes a migration must move for one leaf: every destination device
-    pays for the part of its new slice it does not already hold locally."""
+def _leaf_moved_bytes(leaf, old_sharding, new_sharding) -> Dict[Any, int]:
+    """Bytes a migration must move for one leaf, per destination device:
+    every destination device pays for the part of its new slice it does
+    not already hold locally."""
     shape = tuple(leaf.shape)
     itemsize = np.dtype(leaf.dtype).itemsize
     if old_sharding == new_sharding:
-        return 0
+        return {}
     old_map = {
         d: _norm_index(idx, shape)
         for d, idx in old_sharding.devices_indices_map(shape).items()
     }
     new_map = new_sharding.devices_indices_map(shape)
-    moved = 0
+    moved = {}
     for dev, idx in new_map.items():
         need = _norm_index(idx, shape)
         have = old_map.get(dev)
         vol = _volume(need)
         if have is not None:
             vol -= _overlap(need, have)
-        moved += max(vol, 0) * itemsize
+        moved[dev] = max(vol, 0) * itemsize
     return moved
+
+
+def reshard_bytes_per_device(
+    tree: Any, old_shardings: Any, new_shardings: Any
+) -> Dict[Any, int]:
+    """:func:`reshard_bytes`, broken down by destination device."""
+    leaves, _ = jax.tree_util.tree_flatten(tree)
+    old_leaves = jax.tree_util.tree_leaves(old_shardings)
+    new_leaves = jax.tree_util.tree_leaves(new_shardings)
+    assert len(leaves) == len(old_leaves) == len(new_leaves)
+    per_device: Dict[Any, int] = {}
+    for leaf, old, new in zip(leaves, old_leaves, new_leaves):
+        for dev, b in _leaf_moved_bytes(leaf, old, new).items():
+            per_device[dev] = per_device.get(dev, 0) + int(b)
+    return per_device
 
 
 def reshard_bytes(tree: Any, old_shardings: Any, new_shardings: Any) -> int:
@@ -269,14 +283,7 @@ def reshard_bytes(tree: Any, old_shardings: Any, new_shardings: Any) -> int:
     the cost is computable *before* committing to a migration. Compare with
     :func:`tree_bytes` — what a checkpoint restore moves through storage.
     """
-    total = 0
-    leaves, _ = jax.tree_util.tree_flatten(tree)
-    old_leaves = jax.tree_util.tree_leaves(old_shardings)
-    new_leaves = jax.tree_util.tree_leaves(new_shardings)
-    assert len(leaves) == len(old_leaves) == len(new_leaves)
-    for leaf, old, new in zip(leaves, old_leaves, new_leaves):
-        total += _leaf_moved_bytes(leaf, old, new)
-    return int(total)
+    return sum(reshard_bytes_per_device(tree, old_shardings, new_shardings).values())
 
 
 def leg_state_bytes(tree: Any, shardings: Any, plan: MeshPlan, leg_index: int) -> int:
@@ -332,14 +339,14 @@ def tree_bytes(tree: Any) -> int:
 def train_state_bytes(model) -> int:
     """Param + Adam moment footprint of a model's TrainState, in bytes.
 
-    ``3 ×`` the param bytes: the fp32 master params plus the two Adam
-    moments (m, v) mirror the param tree; scalars are negligible. This is
-    the number the orchestrator matches against an instance shape's
-    ``memory_gb × device_count`` — replacing the seed's hard-coded 16 GB.
+    ``3 ×`` the fp32 param bytes: the fp32 master params plus the two Adam
+    moments (m, v) mirror the param tree; scalars are negligible. Counted
+    in fp32 whatever dtype ``model`` stores its weights in (a serving
+    model stores bf16). This is the number the orchestrator matches
+    against an instance shape's ``memory_gb × device_count`` — replacing
+    the seed's hard-coded 16 GB.
     """
-    from repro.models.common import param_bytes
-
-    return 3 * param_bytes(model.specs)
+    return 3 * np.dtype(np.float32).itemsize * model.param_count()
 
 
 def serve_state_bytes(

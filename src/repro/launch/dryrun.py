@@ -230,8 +230,6 @@ def analyze(compiled, meta: Dict[str, Any]) -> Dict[str, Any]:
     out = dict(meta)
     # raw XLA numbers (loop bodies counted ONCE — kept for reference only)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per partition
-        ca = ca[0] if ca else {}
     out["xla_flops_loop_once"] = float(ca.get("flops", 0.0))
     out["xla_bytes_loop_once"] = float(
         ca.get("bytes accessed", ca.get("bytes accessed0{}", 0.0))

@@ -1,37 +1,52 @@
-"""Production mesh builders.
+"""Mesh builders — every mesh in the package is built here.
+
+Every axis is ``AxisType.Auto``: GSPMD propagates shardings and
+``with_sharding_constraint`` may name any axis. ``jax.make_mesh`` would
+otherwise build Explicit axes, on which the activation constrainer and
+the embedding gather refuse to trace.
 
 Functions, not module-level constants — importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
-from typing import Sequence
+from typing import Any, Sequence
 
 import jax
+from jax.sharding import AxisType, Mesh
+import numpy as np
 
 
-def make_production_mesh(*, multi_pod: bool = False):
+def mesh_over(devices: Sequence[Any], shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """Auto-axes mesh over ``devices`` in the order given, reshaped to ``shape``."""
+    devs = np.asarray(list(devices), dtype=object).reshape(tuple(shape))
+    return Mesh(devs, tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """Auto-axes mesh over the first ``prod(shape)`` local devices."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
+
+
+def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """TPU v5e production mesh: 16×16 = 256 chips/pod; 2 pods = 512 chips.
 
     The ``pod`` axis is pure data parallelism (one gradient all-reduce per
     step crosses the DCN); ``data`` is within-pod DP/FSDP; ``model`` is
     tensor/expert parallelism over ICI.
     """
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
-def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Arbitrary mesh (elastic re-meshing, tests)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def make_host_mesh(model_parallel: int = 1):
+def make_host_mesh(model_parallel: int = 1) -> Mesh:
     """Mesh over whatever devices exist locally (smoke tests: 1 CPU)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"))
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 # TPU v5e hardware constants (per chip) — used by the roofline analysis.

@@ -6,10 +6,13 @@ layout's rule tables (``param_shardings`` / ``cache_shardings`` /
 ``batch_shardings``), the activation constrainer is threaded through the
 steps, and the decode cache is donated — on a 1-device host mesh this
 degenerates to the unsharded path, on a multi-device pool it serves
-sharded with zero code change.
+sharded with zero code change. Weights are stored in the compute dtype
+(``serving_config``) and made on the device (``init_params``). The
+reduced smoke config is the default; ``--no-reduced`` serves the
+published widths.
 
     python -m repro.launch.serve --arch <id> [--batch 4] [--prompt-len 64]
-        [--new-tokens 16] [--int8-cache] [--model-parallel 1]
+        [--new-tokens 16] [--int8-cache] [--model-parallel 1] [--no-reduced]
 
 ``--plan`` mode (the serving-fleet subsystem, ``repro.serve``): serve on
 an :class:`ElasticMeshManager` plan instead of the host mesh, so a
@@ -29,6 +32,7 @@ host-mesh path below runs unchanged (bit-exact with pre-plan serve.py).
         [--cache-policy drop|migrate]
 """
 import argparse
+import dataclasses
 import json
 import time
 
@@ -36,19 +40,39 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import ShardingLayout, get_arch, list_archs
+from repro.config import ModelConfig, ShardingLayout, get_arch, list_archs
 from repro.dist import (
     batch_shardings,
     cache_shardings,
     make_activation_constrainer,
     param_shardings,
 )
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.obs import get_logger
 from repro.train.steps import build_decode_step, build_prefill_step
 
 log = get_logger("launch.serve")
+
+
+def serving_config(arch: str, reduced: bool = True) -> ModelConfig:
+    """The served configuration: weights stored in the compute dtype.
+
+    A server keeps no float32 master copy — every use casts the weights to
+    the compute dtype anyway, so storing them there halves the HBM they
+    take and changes no output. ``reduced=False`` serves the published
+    widths."""
+    cfg = get_arch(arch)
+    if reduced:
+        cfg = cfg.reduced()
+    return dataclasses.replace(cfg, param_dtype=cfg.dtype)
+
+
+def init_params(model, shardings, seed: int = 0):
+    """Seeded random weights, made on the device directly under
+    ``shardings`` (no host copy, no full-size float32 staging)."""
+    return jax.jit(model.init, out_shardings=shardings)(jax.random.key(seed))
 
 
 def _serve_batch(cfg, B, S):
@@ -89,92 +113,102 @@ def _serve_steps(model, cfg, layout, mesh, batch, total, int8):
     return p_sh, c_sh, in_sh, prefill, decode
 
 
-def engine_plan_main(args) -> None:
-    """Serve on ElasticMeshManager plans through the continuous-batching
-    decode engine (paged KV pool). A revocation sheds every in-flight
-    request from the dying engine and resumes it — committed tokens
-    included — on a fresh engine over the replacement plan, with the same
-    params-only byte accounting as the legacy path (the paged pool always
-    follows drop-and-reprefill semantics: pages die with the instance)."""
+def serve_engine_plans(model, layout, counts, prompts, new_tokens, *,
+                       revoke_after: int = 0, man=None, seed: int = 0):
+    """Serve ``prompts`` through the continuous-batching decode engine
+    (paged KV pool) on ElasticMeshManager plans, one lane per prompt.
+
+    With a second entry in ``counts``, a revocation after
+    ``revoke_after`` steps sheds every in-flight request from the dying
+    engine and resumes it — committed tokens included — on a fresh engine
+    over the replacement plan, after a params-only cross-mesh reshard (the
+    paged pool always follows drop-and-reprefill semantics: pages die with
+    the instance). Returns ``(report, plans, params)``: the report holds
+    the token rows and the byte accounting, ``plans`` the plans served on
+    in order, ``params`` the weights as the last plan holds them.
+    """
     from repro.dist import ElasticMeshManager, reshard_tree
-    from repro.dist.meshplan import ThroughputTracker
+    from repro.dist.meshplan import (
+        ThroughputTracker,
+        live_shardings,
+        reshard_bytes_per_device,
+    )
     from repro.models.layers import PAGE_SIZE
     from repro.serve.autoscale import drain_replica
     from repro.serve.engine import DecodeEngine, Request
-    from repro.serve.migrate import (
-        assert_params_only,
-        replica_param_bytes_moved,
-    )
+    from repro.serve.migrate import assert_params_only
 
+    man = man or ElasticMeshManager()
+    tracker = ThroughputTracker()
+    B = len(prompts)
+    total = max(len(p) for p in prompts) + new_tokens
+    num_pages = B * (-(-total // PAGE_SIZE)) + 1
+
+    def engine_on(plan):
+        return DecodeEngine(
+            model, layout, plan.mesh, lanes=B, num_pages=num_pages,
+            max_context=total, tracker=tracker, tracker_key=plan.key,
+        )
+
+    plans = [man.plan_for(counts[0])]
+    engines = [engine_on(plans[0])]
+    params = init_params(model, engines[0].param_sh, seed)
+    for b, prompt in enumerate(prompts):
+        engines[0].submit(Request(rid=b, prompt=prompt, max_new_tokens=new_tokens))
+    log.info("engine plan up", devices=plans[0].device_count,
+             mesh=str(plans[0].mesh_shape), lanes=B, pages=num_pages)
+
+    report = {"params_bytes": 0, "params_bytes_per_device": {}, "cache_bytes": 0,
+              "train_path_bytes": 0, "migrated_at": None, "cache_policy": "drop"}
+    revoke_after = revoke_after if len(counts) > 1 else 0
+    i = 0
+    while engines[-1].in_flight:
+        if revoke_after and i == revoke_after:
+            # the revocation is the same move a scale-down makes: drain
+            # the dying engine's streams onto the replacement replica
+            plans.append(man.plan_for(counts[1]))
+            engines.append(engine_on(plans[-1]))
+            dst = engines[-1].param_sh
+            moved = reshard_bytes_per_device(params, live_shardings(params), dst)
+            params = reshard_tree(params, dst)
+            report["params_bytes"] = sum(moved.values())
+            report["params_bytes_per_device"] = {str(d.id): b for d, b in moved.items()}
+            report["train_path_bytes"] = assert_params_only(report["params_bytes"], model)
+            report["migrated_at"] = i
+            n_drained = drain_replica(engines[-2], engines[-1])
+            log.info("revoked: streams drained to replacement", step=i,
+                     shed=n_drained, devices=plans[-1].device_count,
+                     mesh=str(plans[-1].mesh_shape),
+                     params_bytes=report["params_bytes"],
+                     train_path_bytes=report["train_path_bytes"])
+        engines[-1].step(params)
+        i += 1
+
+    done = {c.rid: c.tokens for e in engines for c in e.completions}
+    report["tokens"] = [done[b] for b in range(B)]
+    report["measured_steps_per_sec"] = {
+        f"{k[1][0]}x{k[1][1]}": round(v, 3) for k, v in tracker.measured.items()
+    }
+    report["engine_tokens_per_sec"] = round(engines[-1].measured_tokens_per_sec, 3)
+    return report, plans, params
+
+
+def engine_plan_main(args) -> None:
+    """``--plan ... --engine``: :func:`serve_engine_plans` on the seeded
+    serving batch, reported as a ``PLAN_JSON`` line."""
     if args.cache_policy != "drop":
         raise SystemExit("--engine supports --cache-policy drop only "
                          "(pool pages die with the instance)")
 
-    cfg = get_arch(args.arch).reduced()
-    model = build_model(cfg)
-    layout = ShardingLayout(int8_kv_cache=args.int8_cache)
-    man = ElasticMeshManager()
+    cfg = serving_config(args.arch, args.reduced)
     counts = [int(x) for x in args.plan.split(",")]
-    tracker = ThroughputTracker()
-
-    B, S = args.batch, args.prompt_len
-    total = S + args.new_tokens
-    num_pages = B * (-(-total // PAGE_SIZE)) + 1
-    prompts = np.asarray(_serve_batch(cfg, B, S)["tokens"])
-    params_host = model.init(jax.random.key(0))
-
-    plan = man.plan_for(counts[0])
-    engine = DecodeEngine(
-        model, layout, plan.mesh, lanes=B, num_pages=num_pages,
-        max_context=total, tracker=tracker, tracker_key=plan.key,
+    prompts = np.asarray(_serve_batch(cfg, args.batch, args.prompt_len)["tokens"])
+    report, _, _ = serve_engine_plans(
+        build_model(cfg), ShardingLayout(int8_kv_cache=args.int8_cache),
+        counts, list(prompts), args.new_tokens, revoke_after=args.revoke_after,
     )
-    params = jax.device_put(params_host, engine.param_sh)
-    for b in range(B):
-        engine.submit(Request(rid=b, prompt=prompts[b],
-                              max_new_tokens=args.new_tokens))
-    log.info("engine plan up", devices=plan.device_count,
-             mesh=str(plan.mesh_shape), lanes=B, pages=num_pages)
-
-    migrated = {"params_bytes": 0, "cache_bytes": 0, "train_path_bytes": 0,
-                "migrated_at": None, "cache_policy": "drop"}
-    revoke_after = args.revoke_after if len(counts) > 1 else 0
-    i = 0
-    while engine.in_flight:
-        if revoke_after and i == revoke_after:
-            # the revocation is the same move a scale-down makes: drain
-            # the dying engine's streams onto the replacement replica
-            dying = engine
-            plan = man.plan_for(counts[1])
-            engine = DecodeEngine(
-                model, layout, plan.mesh, lanes=B, num_pages=num_pages,
-                max_context=total, tracker=tracker, tracker_key=plan.key,
-            )
-            moved = replica_param_bytes_moved(params, engine.param_sh)
-            params = reshard_tree(params, engine.param_sh)
-            migrated["params_bytes"] = moved
-            migrated["train_path_bytes"] = assert_params_only(moved, model)
-            migrated["migrated_at"] = i
-            n_drained = drain_replica(dying, engine)
-            log.info("revoked: streams drained to replacement", step=i,
-                     shed=n_drained, devices=plan.device_count,
-                     mesh=str(plan.mesh_shape),
-                     params_bytes=migrated["params_bytes"],
-                     train_path_bytes=migrated["train_path_bytes"])
-        engine.step(params)
-        i += 1
-
-    done = {c.rid: c.tokens for c in engine.completions}
-    rows = np.asarray([done[b] for b in range(B)], np.int32)
-    sps = {f"{k[1][0]}x{k[1][1]}": round(v, 3) for k, v in tracker.measured.items()}
-    print("first row:", rows[0].tolist())
-    print("PLAN_JSON " + json.dumps({
-        "plans": counts,
-        "engine": True,
-        "tokens": rows.tolist(),
-        "measured_steps_per_sec": sps,
-        "engine_tokens_per_sec": round(engine.measured_tokens_per_sec, 3),
-        **migrated,
-    }))
+    print("first row:", report["tokens"][0])
+    print("PLAN_JSON " + json.dumps({"plans": counts, "engine": True, **report}))
 
 
 def plan_main(args) -> None:
@@ -190,7 +224,7 @@ def plan_main(args) -> None:
         replica_param_bytes_moved,
     )
 
-    cfg = get_arch(args.arch).reduced()
+    cfg = serving_config(args.arch, args.reduced)
     model = build_model(cfg)
     layout = ShardingLayout(int8_kv_cache=args.int8_cache)
     man = ElasticMeshManager()
@@ -200,13 +234,12 @@ def plan_main(args) -> None:
     B, S = args.batch, args.prompt_len
     total = S + args.new_tokens
     batch = _serve_batch(cfg, B, S)
-    params_host = model.init(jax.random.key(0))
 
     plan = man.plan_for(counts[0])
     p_sh, c_sh, in_sh, prefill, decode = _serve_steps(
         model, cfg, layout, plan.mesh, batch, total, args.int8_cache
     )
-    params = jax.device_put(params_host, p_sh)
+    params = init_params(model, p_sh)
     batch = jax.device_put(batch, in_sh)
 
     migrated = {"params_bytes": 0, "cache_bytes": 0, "train_path_bytes": 0,
@@ -291,25 +324,17 @@ def plan_main(args) -> None:
 
 def host_main(args) -> None:
     """The legacy host-mesh path: lock-step batched prefill + decode."""
-    cfg = get_arch(args.arch).reduced()
+    cfg = serving_config(args.arch, args.reduced)
     model = build_model(cfg)
     layout = ShardingLayout(int8_kv_cache=args.int8_cache)
     mesh = make_host_mesh(model_parallel=args.model_parallel)
     constrain = make_activation_constrainer(mesh, layout, cfg)
 
     p_sh = param_shardings(model.specs, mesh, layout)
-    params = jax.device_put(model.init(jax.random.key(0)), p_sh)
+    params = init_params(model, p_sh)
 
     B, S = args.batch, args.prompt_len
-    batch = {"tokens": jax.random.randint(jax.random.key(1), (B, S), 0, cfg.vocab_size, jnp.int32)}
-    if cfg.encoder_layers:
-        batch["frames"] = jax.random.normal(
-            jax.random.key(2), (B, cfg.encoder_seq_len, cfg.d_model), jnp.bfloat16
-        )
-    if cfg.vision_tokens:
-        batch["patches"] = jax.random.normal(
-            jax.random.key(3), (B, cfg.vision_tokens, cfg.vision_width), jnp.bfloat16
-        )
+    batch = _serve_batch(cfg, B, S)
 
     total = S + args.new_tokens
     in_sh = batch_shardings(batch, mesh)
@@ -370,6 +395,10 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=8)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the reduced smoke config (default); "
+                         "--no-reduced serves the published widths")
     ap.add_argument("--int8-cache", action="store_true")
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--plan", default="",
@@ -392,6 +421,7 @@ def main() -> None:
                          "path (replay with python -m repro.obs.replay, "
                          "render with python -m repro.obs.export)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.trace:
         from repro.obs.export import write_jsonl
         from repro.obs.recorder import recording

@@ -1,10 +1,10 @@
 """Training launcher.
 
-    python -m repro.launch.train --arch <id> [--steps N] [--reduced]
-        [--spot-mode siwoft|checkpoint|hybrid|none] [--layout baseline]
+    python -m repro.launch.train --arch <id> [--steps N] [--no-reduced]
+        [--spot-mode siwoft|checkpoint|hybrid|none]
 
-On real hardware this binds to the production mesh (jax.distributed over
-pods); on this container it runs the reduced config on the host mesh. With
+It trains on the host mesh: the reduced config by default, the published
+widths with ``--no-reduced``. With
 ``--spot-mode`` the run goes through the P-SIWOFT orchestrator (the paper's
 provisioning layer); with ``none`` it is a plain training loop.
 """
@@ -18,6 +18,7 @@ from repro.config import ShardingLayout, TrainConfig, get_arch, list_archs
 from repro.core import generate_markets, split_history_future
 from repro.core.orchestrator import SpotTrainingOrchestrator
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.obs import get_logger
@@ -73,7 +74,10 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the reduced smoke config (default); "
+                         "--no-reduced trains the published widths")
     ap.add_argument("--spot-mode", default="none",
                     choices=["none", "siwoft", "checkpoint", "hybrid"])
     ap.add_argument("--ckpt-dir", default="")
@@ -82,6 +86,7 @@ def main() -> None:
                     help="record the structured event timeline to this JSONL "
                          "path (replay with python -m repro.obs.replay)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.trace:
         from repro.obs.export import write_jsonl
         from repro.obs.recorder import recording

@@ -584,11 +584,12 @@ def decode_attention_paged(
     to the reserved trash page and attends over zero positions, producing
     a deterministic output the engine never reads.
 
-    ``use_kernel`` dispatches to the Pallas kernel (bf16/f32 pools only);
-    the default is the pure-jnp oracle, and int8 pools always take the
-    gather path with dequantization scoped to the gathered pages —
-    O(seq_len) dequant per token, unlike the dense ``decode_attention``
-    path which dequantizes the whole cache each step.
+    ``use_kernel`` dispatches to the Pallas kernel, which takes bf16/f32
+    pools only: an int8 pool with ``use_kernel=True`` raises. The default
+    is the pure-jnp oracle; int8 pools take the gather path with
+    dequantization scoped to the gathered pages — O(seq_len) dequant per
+    token, unlike the dense ``decode_attention`` path which dequantizes
+    the whole cache each step.
     """
     from repro.kernels.paged_attention import (
         paged_attention_ref, paged_decode_attention,
@@ -600,6 +601,11 @@ def decode_attention_paged(
     k_pages = cache["k_pages"]
     P, ps = k_pages.shape[:2]
     int8 = k_pages.dtype == jnp.int8
+    if int8 and use_kernel:
+        raise NotImplementedError(
+            "the paged-attention kernel takes bf16/f32 pools; an int8 pool "
+            "needs use_kernel=False (the gather path dequantizes its pages)"
+        )
 
     pos = seq_lens.astype(jnp.int32)
     q, k_new, v_new = _project_qkv(params, x, x, cfg)

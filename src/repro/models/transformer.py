@@ -134,7 +134,13 @@ def model_specs(cfg: ModelConfig) -> Dict[str, Any]:
         }
     if cfg.vision_tokens:  # internvl stub projector
         spec["vision_proj"] = ParamSpec((cfg.vision_width, d), ("vit_embed", "embed"))
-    return spec
+    # storage dtype: float32 master weights for training; a server stores
+    # the compute dtype (every use casts to it, so the outputs are the same)
+    return jax.tree_util.tree_map(
+        lambda s: dataclasses.replace(s, dtype=cfg.param_dtype),
+        spec,
+        is_leaf=lambda x: isinstance(x, ParamSpec),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -300,15 +306,12 @@ def _attn_full(params, h, positions, cfg, opts):
     q, k, v = layers._constrain_qkv(q, k, v, opts)
     window = cfg.window if cfg.attention == AttentionKind.SLIDING else 0
     if opts.attn_impl == "flash":
-        # Pallas flash-attention prefill (serving hot path). Same math as
-        # the jnp blockwise path (allclose-swept in tests/test_kernels.py);
-        # interpret mode keeps it runnable on CPU CI.
+        # Pallas flash-attention prefill. Same math as the jnp blockwise
+        # path (allclose-swept in tests/test_kernels.py). It compiles for
+        # the TPU; there is no interpret-mode fallback on other backends.
         from repro.kernels.flash_attention import flash_attention
 
-        out = flash_attention(
-            q, k, v, True, window, 0, 128, 128,
-            jax.default_backend() != "tpu",
-        )
+        out = flash_attention(q, k, v, True, window, 0, 128, 128)
     else:
         out = layers.blockwise_attention(
             q, k, v,

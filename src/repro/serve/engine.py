@@ -125,6 +125,7 @@ class DecodeEngine:
         self.decode_seconds = 0.0
         self.prefilled_tokens = 0
         self.steps = 0                      # lane-event trace clock
+        self.last_logits = None             # (lanes, vocab) of the last step
 
         self._int8 = layout.int8_kv_cache
         self._free_pages = deque(range(num_pages - 1))  # last page = trash
@@ -207,8 +208,9 @@ class DecodeEngine:
     # -- admission ----------------------------------------------------------
 
     def _pages_needed(self, req: Request) -> int:
-        n_resume = len(req.resume_tokens) if req.resume_tokens is not None else 0
-        total = len(req.prompt) + n_resume + req.max_new_tokens
+        # resumed tokens count toward max_new_tokens: a stream never holds
+        # more than prompt + max_new_tokens positions, resumed or not
+        total = len(req.prompt) + req.max_new_tokens
         return -(-total // self.page_size)
 
     def _prefill_for(self, length: int):
@@ -366,7 +368,8 @@ class DecodeEngine:
             logits, self.cache = self._decode(
                 params, self.cache, tok_d, sl_d, bt_d
             )
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            self.last_logits = logits[:, -1]
+            nxt = jnp.argmax(self.last_logits, axis=-1).astype(jnp.int32)
             jax.block_until_ready(nxt)
             dt = time.perf_counter() - t0  # repro-lint: disable=D001
         self.decode_seconds += dt

@@ -257,3 +257,51 @@ def test_paged_int8_scoped_dequant_pins_dense_fallback_bitwise():
     a = np.asarray(y_scoped, np.float32)
     b = np.asarray(y_full, np.float32)
     assert np.array_equal(a, b), np.abs(a - b).max()
+
+
+def test_paged_int8_pool_refuses_the_kernel():
+    """An int8 pool never enters the Pallas kernel, and asking for it is
+    an error rather than a silent switch to the gather path."""
+    import dataclasses
+
+    from repro.models import layers
+    from repro.models.common import init_params
+
+    cfg = dataclasses.replace(get_arch("qwen3-4b").reduced(), num_layers=1)
+    params = init_params(layers.attention_spec(cfg), jax.random.key(0))
+    P, ps, KVH, hd = 3, layers.PAGE_SIZE, cfg.num_kv_heads, cfg.resolved_head_dim
+    kq, ks = layers._quantize_kv(jnp.zeros((P, ps, KVH, hd), jnp.bfloat16))
+    cache = {"k_pages": kq, "v_pages": kq, "k_scale": ks, "v_scale": ks}
+    x = jnp.zeros((1, 1, cfg.d_model), jnp.bfloat16)
+    table, lens = jnp.zeros((1, 2), jnp.int32), jnp.ones((1,), jnp.int32)
+    with pytest.raises(NotImplementedError, match="int8"):
+        layers.decode_attention_paged(
+            params, cache, x, lens, table, cfg, use_kernel=True, interpret=True
+        )
+
+
+def test_engine_resumes_a_stream_at_the_context_limit(served):
+    """A stream whose prompt + max_new_tokens fills the whole context is
+    shed mid-way and resumes on an engine of the same context: its resumed
+    tokens count toward max_new_tokens, so it needs no more pages than it
+    did at first admission, and it completes token-identically."""
+    cfg, model, layout, mesh, params, *_ = served
+    ctx = 48
+    prompt = np.random.RandomState(3).randint(0, cfg.vocab_size, ctx - NEW).astype(np.int32)
+    req = Request(rid=9, prompt=prompt, max_new_tokens=NEW)
+
+    def engine():
+        return DecodeEngine(model, layout, mesh, lanes=1, num_pages=4, max_context=ctx)
+
+    whole = engine()
+    whole.submit(req)
+    (ref,) = whole.run(params)
+    first = engine()
+    first.submit(req)
+    for _ in range(3):
+        first.step(params)
+    second = engine()
+    for q in first.shed():
+        second.submit(q)
+    (done,) = second.run(params)
+    assert done.tokens == ref.tokens

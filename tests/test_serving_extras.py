@@ -87,3 +87,52 @@ def test_sliding_window_single_layer_evicts():
     cache3["v"] = cache["v"].at[:, 6].set(-99.0)
     y3, _ = layers.decode_attention(params, cache3, x, pos, cfg)
     assert np.abs(np.asarray(y1, np.float32) - np.asarray(y3, np.float32)).max() > 1e-3
+
+
+def test_served_weights_are_stored_in_the_compute_dtype():
+    """A server stores bf16 weights (no float32 master copy); the outputs
+    are the same as float32 weights cast at use, and the training
+    footprint is still counted in float32."""
+    from repro.dist import param_shardings, train_state_bytes
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.serve import init_params, serving_config
+    from repro.config import ShardingLayout
+    from repro.models.common import param_bytes
+
+    full = serving_config("qwen3-4b", reduced=False)
+    assert full.param_dtype == "bfloat16" and full.d_model == 2560
+    assert full.num_layers == 36
+
+    cfg = serving_config("qwen3-4b")
+    served, trained = build_model(cfg), build_model(get_arch("qwen3-4b").reduced())
+    assert param_bytes(served.specs) * 2 == param_bytes(trained.specs)
+    assert train_state_bytes(served) == train_state_bytes(trained)
+
+    mesh = make_host_mesh()
+    p16 = init_params(served, param_shardings(served.specs, mesh, ShardingLayout()))
+    p32 = init_params(trained, param_shardings(trained.specs, mesh, ShardingLayout()))
+    assert {x.dtype for x in jax.tree_util.tree_leaves(p16)} == {jnp.dtype(jnp.bfloat16)}
+    tokens = jax.random.randint(jax.random.key(1), (1, 12), 0, cfg.vocab_size, jnp.int32)
+    a = served.forward(p16, {"tokens": tokens})[0]
+    b = trained.forward(p32, {"tokens": tokens})[0]
+    np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+def test_compile_cache_dir_from_env_or_repo_root(monkeypatch):
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/placed/elsewhere")
+        assert compile_cache.use_compile_cache() == "/placed/elsewhere"
+        # JAX reads the variable itself; nothing here overrides it
+        assert jax.config.jax_compilation_cache_dir is None
+
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        path = compile_cache.use_compile_cache()
+        assert path == str(compile_cache.DEFAULT_DIR)
+        assert compile_cache.DEFAULT_DIR.parent == compile_cache.pathlib.Path(__file__).parents[1]
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
