@@ -30,8 +30,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from repro.launch.mesh import mesh_over
-
 
 def mesh_shape_for(n_devices: int) -> Tuple[int, int]:
     """Deterministic (data, model) factorization of ``n_devices``."""
@@ -97,7 +95,8 @@ class ElasticMeshManager:
         plan = self._plans.get(n)
         if plan is None:
             shape = mesh_shape_for(n)
-            mesh = mesh_over(self.devices[:n], shape, ("data", "model"))
+            devs = np.asarray(self.devices[:n], dtype=object).reshape(shape)
+            mesh = jax.sharding.Mesh(devs, ("data", "model"))
             plan = MeshPlan(
                 requested_devices=int(device_count),
                 device_count=n,
@@ -140,7 +139,10 @@ class ElasticMeshManager:
         plan = self._alloc_plans.get(key)
         if plan is None:
             shape = mesh_shape_for(honored_total)
-            mesh = mesh_over(self.devices[:honored_total], shape, ("data", "model"))
+            devs = np.asarray(
+                self.devices[:honored_total], dtype=object
+            ).reshape(shape)
+            mesh = jax.sharding.Mesh(devs, ("data", "model"))
             spans, lo = [], 0
             for s in shares:
                 spans.append((lo, lo + s))

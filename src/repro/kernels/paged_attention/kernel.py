@@ -15,10 +15,11 @@ pool's own, which the TPU's block tiling rule requires (a one-head block
 ``(1, page_size, 1, hd)`` is refused by the compiler). The page's
 ``page_size × KVH`` key rows are scored against all ``H`` query heads in
 one matmul, and the pairs whose key head is not the query's own GQA head
-are masked out; decode is bound by the page reads, so the extra MXU work
-costs nothing that matters. A TPU Pallas grid executes sequentially per
-core, so the online-softmax state (m, l, acc) for the H query heads lives
-in VMEM scratch and is carried across pages, exactly like the prefill
+are masked out: KVH times the score, exp and PV work a per-head block
+would do, at a cost not measured yet (a ``(P, KVH, page_size, hd)`` pool
+would give a legal per-head block with none of it). A TPU Pallas grid
+executes sequentially per core, so the online-softmax state (m, l, acc)
+for the H query heads lives in VMEM scratch and is carried across pages, exactly like the prefill
 flash kernel.
 
 Pages past ``seq_lens[b]`` are skipped with ``pl.when``, and their index

@@ -1,26 +1,20 @@
-"""Mesh builders — every mesh in the package is built here.
+"""Mesh builders for the launchers, benches and tests.
 
 Every axis is ``AxisType.Auto``: GSPMD propagates shardings and
 ``with_sharding_constraint`` may name any axis. ``jax.make_mesh`` would
 otherwise build Explicit axes, on which the activation constrainer and
-the embedding gather refuse to trace.
+the embedding gather refuse to trace. (``dist.meshplan`` builds its plans
+with ``jax.sharding.Mesh``, whose axes are Auto already.)
 
 Functions, not module-level constants — importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Sequence
 
 import jax
 from jax.sharding import AxisType, Mesh
-import numpy as np
-
-
-def mesh_over(devices: Sequence[Any], shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """Auto-axes mesh over ``devices`` in the order given, reshaped to ``shape``."""
-    devs = np.asarray(list(devices), dtype=object).reshape(tuple(shape))
-    return Mesh(devs, tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:

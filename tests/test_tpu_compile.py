@@ -1,12 +1,13 @@
-"""The serving path's Pallas kernels compile for a TPU v5e chip.
+"""The serving path's Pallas kernels and the train step compile for a TPU v5e.
 
 Every chip compile of the test suite lives in this one file. The chip is
 described (``v5e:2x2``), not attached: the TPU compiler refuses here what
 it would refuse on the chip — block shapes off the tiling, too much VMEM
 — which interpret-mode runs never see. Nothing runs, so these tests say
-nothing about results or times. Shapes are qwen3-4b's: 32 query heads,
-8 KV heads, head_dim 128; the paged pool is 8 lanes at 2048 context in
-pages of 16, the flash prefill 2048 tokens.
+nothing about results or times. Kernel shapes are qwen3-4b's: 32 query
+heads, 8 KV heads, head_dim 128; the paged pool is 8 lanes at 2048 context
+in pages of 16, the flash prefill 2048 tokens. The train step is the
+orchestrator's, on each mesh a 4-chip pool is planned into.
 
 The topology is described inside a fixture, never at import, and the
 persistent compilation cache is off around these compiles (an entry
@@ -91,3 +92,29 @@ def test_flash_backward_compiles_at_prefill_2048(one_chip):
     compiled = grad.lower(*_flash_args(one_chip)).compile()
     # the forward plus the two backward kernels (dk/dv and dq)
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("chips", [1, 2, 4])
+def test_orchestrator_train_step_compiles_on_every_plan(topo, chips):
+    """The xLSTM train step the orchestrator jits, at the default layout
+    (full remat, FSDP over the data axis), on each mesh ``plan_for`` gives
+    a 4-chip pool. Reduced widths hit the same compiler fault on (2, 1)
+    as the published ones when the step leaves the TPU fusion mode on."""
+    from repro.config import ShardingLayout, TrainConfig, get_arch
+    from repro.dist import ElasticMeshManager
+    from repro.models import build_model
+    from repro.train.loop import make_jitted_step
+    from repro.train.steps import abstract_train_state
+
+    plan = ElasticMeshManager(devices=topo.devices).plan_for(chips)
+    assert plan.device_count == chips
+    model = build_model(get_arch("xlstm-350m").reduced())
+    jitted, state_sh = make_jitted_step(model, TrainConfig(), ShardingLayout(), plan.mesh)
+    state = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        abstract_train_state(model), state_sh,
+    )
+    repl = jax.sharding.NamedSharding(plan.mesh, jax.sharding.PartitionSpec())
+    batch = {k: jax.ShapeDtypeStruct((4, 512), jnp.int32, sharding=repl)
+             for k in ("tokens", "labels")}
+    jitted.lower(state, batch).compile()
