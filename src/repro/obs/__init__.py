@@ -15,6 +15,11 @@ discipline the scalar billing oracles enforce on the vectorized core.
   :class:`~repro.obs.recorder.NullRecorder` DEFAULT: with telemetry off,
   instrumented code performs one attribute check per loop and constructs
   nothing, so every pinned bit-exact path stays byte-identical;
+* :mod:`repro.obs.spans`    — :func:`span`, a host span in the JAX
+  profiler's own trace (a thin ``jax.profiler.TraceAnnotation``): where
+  the recorder's events run on each loop's own clock, spans share the
+  device's, so a trace reduction can name what the host did while the
+  chip idled; off unless a profiler trace runs;
 * :mod:`repro.obs.export`   — JSONL event logs (exact float round-trip)
   and Chrome/Perfetto ``trace_event`` export, one track per
   market/replica/engine lane;
@@ -30,6 +35,7 @@ See ``docs/observability.md`` for the event schema and replay contract.
 from repro.obs import events
 from repro.obs.log import get_logger
 from repro.obs.recorder import NullRecorder, Recorder, current, recording
+from repro.obs.spans import span
 
 __all__ = [
     "NullRecorder",
@@ -38,4 +44,5 @@ __all__ = [
     "events",
     "get_logger",
     "recording",
+    "span",
 ]

@@ -22,42 +22,31 @@ from typing import Dict, Iterator, List
 
 
 class Recorder:
-    """Append-only in-memory event sink with counters/gauges/histograms.
+    """Append-only in-memory event sink with gauges.
 
     ``events`` holds typed event instances in emission order (the global
     order *is* the sequence number — ``events[i]`` was the i-th emit).
-    Counters/gauges/histograms are side telemetry and never participate
-    in the replay oracle.
+    Gauges are side telemetry and never participate in the replay oracle.
     """
 
     enabled = True
 
     def __init__(self) -> None:
         self.events: List[object] = []
-        self.counters: Dict[str, int] = {}
         self.gauge_values: Dict[str, float] = {}
         self.gauge_series: Dict[str, List[tuple]] = {}
-        self.histograms: Dict[str, List[float]] = {}
 
     def emit(self, event) -> None:
         self.events.append(event)
-
-    def count(self, name: str, delta: int = 1) -> None:
-        self.counters[name] = self.counters.get(name, 0) + delta
 
     def gauge(self, name: str, t: float, value: float) -> None:
         self.gauge_values[name] = value
         self.gauge_series.setdefault(name, []).append((t, value))
 
-    def observe(self, name: str, value: float) -> None:
-        self.histograms.setdefault(name, []).append(value)
-
     def clear(self) -> None:
         self.events.clear()
-        self.counters.clear()
         self.gauge_values.clear()
         self.gauge_series.clear()
-        self.histograms.clear()
 
 
 class NullRecorder:
@@ -72,13 +61,7 @@ class NullRecorder:
     def emit(self, event) -> None:  # pragma: no cover - guarded out
         pass
 
-    def count(self, name: str, delta: int = 1) -> None:  # pragma: no cover
-        pass
-
     def gauge(self, name: str, t: float, value: float) -> None:  # pragma: no cover
-        pass
-
-    def observe(self, name: str, value: float) -> None:  # pragma: no cover
         pass
 
 

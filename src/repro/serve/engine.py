@@ -28,11 +28,19 @@ cache pages into the request's reserved pool pages. Per-batch decode wall
 times feed a ``ThroughputTracker`` so the fleet simulator can consume
 MEASURED tokens/sec (``FleetSimulator`` ``throughput_mode="engine"``)
 instead of the closed-form analytic table.
+
+Each phase of an admission (``serve.admit``: ``serve.prefill``,
+``serve.pack``, ``serve.first_token``) and of a step (``serve.batch``,
+``serve.decode``, ``serve.readback``, ``serve.commit``) runs inside a
+profiler span (``repro.obs.span``), and so does each garbage collection
+(``serve.gc``): a profiler trace then names what the host did while the
+chip idled. Without a running trace the spans record nothing.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import gc
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
@@ -44,6 +52,28 @@ import numpy as np
 from repro.models.layers import PAGE_SIZE
 from repro.obs import events as obs_ev
 from repro.obs.recorder import current as obs_current
+from repro.obs.spans import span
+
+GC_SPAN = "serve.gc"
+_gc_open = None         # the span of the collection under way
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """A ``gc.callbacks`` hook: every collection in the process, not only
+    those the engine sets off, runs inside a ``serve.gc`` span."""
+    global _gc_open
+    if phase == "start":
+        _gc_open = span(GC_SPAN, generation=info["generation"])
+        _gc_open.__enter__()
+    elif _gc_open is not None:
+        _gc_open.__exit__(None, None, None)
+        _gc_open = None
+
+
+def _trace_gc() -> None:
+    """Hook ``_gc_span`` once per process; a later call does nothing."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 @dataclasses.dataclass
@@ -111,6 +141,7 @@ class DecodeEngine:
         )
 
         assert num_pages >= 2, "pool needs at least one real page + trash"
+        _trace_gc()
         self.model = model
         self.layout = layout
         self.mesh = mesh
@@ -251,7 +282,9 @@ class DecodeEngine:
             if needed > len(self._free_pages):
                 return  # FIFO back-pressure: head-of-line waits for pages
             self._pending.popleft()
-            self._insert(req, [self._free_pages.popleft() for _ in range(needed)])
+            pages = [self._free_pages.popleft() for _ in range(needed)]
+            with span("serve.admit", rid=int(req.rid), prompt_len=len(req.prompt)):
+                self._insert(req, pages)
 
     def _insert(self, req: Request, pages: List[int]) -> None:
         resume = (np.asarray(req.resume_tokens, np.int32)
@@ -260,20 +293,23 @@ class DecodeEngine:
         # which rides the next decode step
         cached = np.concatenate([req.prompt.astype(np.int32), resume[:-1]])
         length = len(cached)
-        prefill = self._prefill_for(length)
         with self.mesh:
-            tokens = jax.device_put(jnp.asarray(cached[None, :]), self._repl)
-            logits, dense = prefill(self._params, {"tokens": tokens})
-            n_dense = dense["blocks"]["k"].shape[2] // self.page_size
-            pack = self._pack_for(n_dense)
-            self.cache = pack(
-                self.cache, dense["blocks"],
-                jnp.asarray(pages[:n_dense], jnp.int32),
-            )
+            with span("serve.prefill"):
+                prefill = self._prefill_for(length)
+                tokens = jax.device_put(jnp.asarray(cached[None, :]), self._repl)
+                logits, dense = prefill(self._params, {"tokens": tokens})
+            with span("serve.pack"):
+                n_dense = dense["blocks"]["k"].shape[2] // self.page_size
+                pack = self._pack_for(n_dense)
+                self.cache = pack(
+                    self.cache, dense["blocks"],
+                    jnp.asarray(pages[:n_dense], jnp.int32),
+                )
             if len(resume):
                 current = int(resume[-1])
             else:
-                current = int(jnp.argmax(logits[0, -1]))
+                with span("serve.first_token"):
+                    current = int(jnp.argmax(logits[0, -1]))
         self.prefilled_tokens += length
         lane = self._lanes.index(None)
         generated = [int(t) for t in resume] if len(resume) else [current]
@@ -351,39 +387,43 @@ class DecodeEngine:
         if not active:
             return self._done[done_before:]
 
-        tokens = np.zeros((self.lanes, 1), np.int32)
-        seq_lens = np.zeros(self.lanes, np.int32)
-        table = np.full((self.lanes, self.max_blocks), -1, np.int32)
-        for i in active:
-            lane = self._lanes[i]
-            tokens[i, 0] = lane.current
-            seq_lens[i] = lane.seq_len
-            table[i, : len(lane.pages)] = lane.pages
-
-        with self.mesh:
-            tok_d = jax.device_put(jnp.asarray(tokens), self._repl)
-            sl_d = jax.device_put(jnp.asarray(seq_lens), self._repl)
-            bt_d = jax.device_put(jnp.asarray(table), self._repl)
-            t0 = time.perf_counter()  # repro-lint: disable=D001
+        # decode_seconds: the whole step as a caller of it sees it, from
+        # the lane arrays to the last lane's bookkeeping
+        t0 = time.perf_counter()  # repro-lint: disable=D001
+        with span("serve.batch", lanes=len(active)):
+            tokens = np.zeros((self.lanes, 1), np.int32)
+            seq_lens = np.zeros(self.lanes, np.int32)
+            table = np.full((self.lanes, self.max_blocks), -1, np.int32)
+            for i in active:
+                lane = self._lanes[i]
+                tokens[i, 0] = lane.current
+                seq_lens[i] = lane.seq_len
+                table[i, : len(lane.pages)] = lane.pages
+            with self.mesh:
+                tok_d = jax.device_put(jnp.asarray(tokens), self._repl)
+                sl_d = jax.device_put(jnp.asarray(seq_lens), self._repl)
+                bt_d = jax.device_put(jnp.asarray(table), self._repl)
+        with self.mesh, span("serve.decode"):
             logits, self.cache = self._decode(
                 params, self.cache, tok_d, sl_d, bt_d
             )
             self.last_logits = logits[:, -1]
             nxt = jnp.argmax(self.last_logits, axis=-1).astype(jnp.int32)
+        with span("serve.readback"):
             jax.block_until_ready(nxt)
-            dt = time.perf_counter() - t0  # repro-lint: disable=D001
+            nxt = np.asarray(nxt)
+        with span("serve.commit"):
+            for i in active:
+                lane = self._lanes[i]
+                lane.seq_len += 1
+                lane.current = int(nxt[i])
+                lane.generated.append(lane.current)
+                self._maybe_finish(i)
+        dt = time.perf_counter() - t0  # repro-lint: disable=D001
         self.decode_seconds += dt
         self.decoded_tokens += len(active)
         if self.tracker is not None:
             self.tracker.observe(self.tracker_key, 1, dt)
-
-        nxt = np.asarray(nxt)
-        for i in active:
-            lane = self._lanes[i]
-            lane.seq_len += 1
-            lane.current = int(nxt[i])
-            lane.generated.append(lane.current)
-            self._maybe_finish(i)
         return self._done[done_before:]
 
     def run(self, params, max_steps: int = 100_000) -> List[Completion]:

@@ -6,6 +6,8 @@ changes nothing), cross-engine log identity (reference and vectorized
 simulators emit the same timeline), and the replay/export CLIs."""
 import dataclasses
 import json
+import pathlib
+import tempfile
 
 from hypothesis import given, settings, strategies as st
 import numpy as np
@@ -370,7 +372,7 @@ def test_orchestrator_replay_bit_exact(host_mesh):
     price_hi=st.floats(0.6, 3.0),
 )
 @settings(max_examples=40, deadline=None)
-def test_random_sessions_replay_bit_exact(n_sessions, seed, price_lo, price_hi, tmp_path):
+def test_random_sessions_replay_bit_exact(n_sessions, seed, price_lo, price_hi):
     """Any run assembled from random sessions survives emit -> JSONL ->
     replay with its Breakdown reconstructed bit-exactly: Python's json
     floats round-trip shortest-repr exact, and replay bills through the
@@ -398,9 +400,12 @@ def test_random_sessions_replay_bit_exact(n_sessions, seed, price_lo, price_hi, 
     events.append(E.breakdown_pin(wall, bd))
     events.append(E.RunEnd(t=wall, wall_hours=wall))
 
-    path = tmp_path / "random.jsonl"
-    write_jsonl(path, events)
-    run = _replay_single(read_jsonl(path))
+    # a directory of its own per example: a function-scoped ``tmp_path``
+    # would be shared by every example Hypothesis draws
+    with tempfile.TemporaryDirectory() as tmp:
+        path = pathlib.Path(tmp) / "random.jsonl"
+        write_jsonl(path, events)
+        run = _replay_single(read_jsonl(path))
     assert _bd_fields(run.breakdown) == _bd_fields(bd)
 
 
