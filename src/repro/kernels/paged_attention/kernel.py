@@ -1,32 +1,25 @@
 """Paged decode attention as a Pallas TPU kernel.
 
 One decode token per sequence attends over that sequence's pages of a
-shared KV block pool (vLLM-style paged KV cache). The physical page for
-grid step (b, j) is read from the *scalar-prefetched* block table inside
-the k/v BlockSpec index maps — ``pltpu.PrefetchScalarGridSpec`` makes
-``block_table``/``seq_lens`` available before the kernel body runs, so
-the DMA engine fetches exactly the pages the sequence occupies and the
-HBM traffic is O(seq_len), not O(max_context) like the dense-cache decode
-path.
+shared KV block pool (vLLM-style paged KV cache). The pools stay in HBM
+(``memory_space=pl.ANY``); the kernel copies only the pages a lane holds,
+so its HBM traffic and its work follow the live positions, not the width
+of the block table.
 
-Grid: (B, max_blocks) with the page axis innermost. One block is one
-whole pool page, ``(1, page_size, KVH, hd)``: its last two dims are the
-pool's own, which the TPU's block tiling rule requires (a one-head block
-``(1, page_size, 1, hd)`` is refused by the compiler). The page's
-``page_size × KVH`` key rows are scored against all ``H`` query heads in
-one matmul, and the pairs whose key head is not the query's own GQA head
-are masked out: KVH times the score, exp and PV work a per-head block
-would do, at a cost not measured yet (a ``(P, KVH, page_size, hd)`` pool
-would give a legal per-head block with none of it). A TPU Pallas grid
-executes sequentially per core, so the online-softmax state (m, l, acc)
-for the H query heads lives in VMEM scratch and is carried across pages, exactly like the prefill
-flash kernel.
+Grid: one step per lane. A step walks its lane's live pages in compute
+blocks of ``pages_per_block`` pages. Each page is one DMA of the pool's
+own ``(page_size, KVH, hd)`` slab (viewed as ``(page_size * KVH, hd)``,
+the same bytes), started from the scalar-prefetched block table into one
+of two VMEM slots: the copies of the next block — the lane's next one, or
+the next lane's first — start before the current block's compute, so the
+DMA engine runs one block ahead across the whole grid. Pages past
+``seq_lens[b]`` are never copied; the lane's last, partial block masks the
+positions past its length. A dead lane (seq_len 0) copies nothing and
+finalizes to a zero vector — deterministic, and never read by the engine.
 
-Pages past ``seq_lens[b]`` are skipped with ``pl.when``, and their index
-map repeats the lane's last live page, so the pipeline issues no DMA for
-them (unassigned table entries are clamped to page 0). A dead lane
-(seq_len 0) runs no page and finalizes to a zero vector — deterministic,
-and never read by the engine.
+Each KV head's rows are read out of the slot with a sublane stride of KVH
+and scored against its own query group only; the online softmax (m, l,
+acc) stays in float32 per head, as in the prefill flash kernel.
 """
 from __future__ import annotations
 
@@ -41,71 +34,126 @@ import numpy as np
 
 NEG_INF = -1e30
 
+# VMEM bytes of one K (or V) compute block: pages_per_block follows from
+# the page's bytes, page_size x KVH x head_dim x itemsize. 256 KiB is 8 of
+# qwen3-4b's bf16 pages (128 positions), the best of 4, 8 and 16 pages on a
+# TPU v5e at the serving benchmark's decode shapes.
+BLOCK_BYTES = 256 * 1024
+
+
+def pages_per_block(page_size: int, kv_heads: int, head_dim: int,
+                    dtype, max_blocks: int) -> int:
+    page_bytes = page_size * kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    return max(1, min(BLOCK_BYTES // page_bytes, max_blocks))
+
 
 def _paged_kernel(
-    bt_ref, sl_ref,                 # scalar-prefetch: block table, seq lens
-    q_ref, k_ref, v_ref,            # VMEM tiles
-    o_ref,                          # output tile
-    m_scr, l_scr, acc_scr,          # VMEM scratch carried over the page axis
+    bt_ref, sl_ref,                 # scalar prefetch: flat block table, seq lens
+    q_ref,                          # (1, KVH, G, hd) VMEM
+    k_hbm, v_hbm,                   # (P, page_size * KVH, hd) in HBM
+    o_ref,                          # (1, KVH, G, hd) VMEM
+    k_buf, v_buf,                   # (2, pages_per_block * page_size * KVH, hd)
+    sems,                           # DMA semaphores, (2 [k, v], 2 [slot])
+    slot_ref,                       # SMEM (1,): slot of the next block to compute
+    k_f32, v_f32,                   # (pages_per_block * page_size * KVH, hd) f32
+    m_scr, l_scr, acc_scr,          # (KVH, G, 1), (KVH, G, 1), (KVH, G, hd) f32
     *,
     sm_scale: float,
     page_size: int,
+    pages_per_block: int,
     n_blocks: int,
-    group: int,
+    n_lanes: int,
 ):
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    kvh = m_scr.shape[0]
+    rows = page_size * kvh                     # pool rows per page
+    block_t = pages_per_block * page_size      # positions per compute block
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def page_copies(lane, blk, slot, go):
+        """Start (``go``) or wait for the copies of block ``blk`` of
+        ``lane``: one per live page, none past the lane's length."""
+        length = sl_ref[lane]
+        for i in range(pages_per_block):
+            j = blk * pages_per_block + i
 
-    seq_len = sl_ref[b]
-    base = j * page_size
+            @pl.when(j * page_size < length)
+            def _():
+                page = jnp.maximum(bt_ref[lane * n_blocks + j], 0)
+                dst = pl.ds(i * rows, rows)
+                for cp in (
+                    pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, dst], sems.at[0, slot]),
+                    pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, dst], sems.at[1, slot]),
+                ):
+                    if go:
+                        cp.start()
+                    else:
+                        cp.wait()
 
-    @pl.when(base < seq_len)
-    def _body():
-        _, ps, kvh, hd = k_ref.shape
-        q = q_ref[0].astype(jnp.float32)                  # (H, hd)
-        # (ps, KVH, hd) -> (ps*KVH, hd): key row c is position c // KVH of
-        # the page, kv head c % KVH
-        k = k_ref[0].astype(jnp.float32).reshape(ps * kvh, hd)
-        v = v_ref[0].astype(jnp.float32).reshape(ps * kvh, hd)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale                                      # (H, ps*KVH)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        own_head = (row // group) == (col % kvh)
-        in_seq = (base + col // kvh) < seq_len
-        s = jnp.where(own_head & in_seq, s, NEG_INF)
+    @pl.when(b == 0)
+    def _first():
+        # stale rows of a partial block are masked to p == 0; zeroing the
+        # slots once keeps them finite, so p * v adds nothing
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        page_copies(0, 0, 0, go=True)
 
-        m_prev = m_scr[...]                               # (H, 1)
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                            # (H, ps*KVH)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )                                                 # (H, hd)
-        acc_scr[...] = acc_scr[...] * corr + pv
-        m_scr[...] = m_new
-        l_scr[...] = l_new
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j == n_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-37)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    length = sl_ref[b]
+    n_live = (length + block_t - 1) // block_t
 
+    def prefetch_next(blk, slot):
+        @pl.when(blk + 1 < n_live)
+        def _():
+            page_copies(b, blk + 1, slot, go=True)
 
-def _page_index(b, j, bt, sl, *, page_size):
-    """Pool page for grid step (b, j): steps past the lane's last live page
-    repeat that page, so the pipeline skips their copy."""
-    last = jnp.maximum(sl[b] - 1, 0) // page_size
-    return (jnp.maximum(bt[b, jnp.minimum(j, last)], 0), 0, 0, 0)
+        @pl.when((blk + 1 >= n_live) & (b + 1 < n_lanes))
+        def _():
+            page_copies(b + 1, 0, slot, go=True)
+
+    def block(blk, carry):
+        slot = slot_ref[0]
+        prefetch_next(blk, 1 - slot)
+        page_copies(b, blk, slot, go=False)
+        pos = blk * block_t + jax.lax.broadcasted_iota(jnp.int32, (1, block_t), 1)
+        valid = pos < length
+        # strided loads take 32-bit rows: stage the block in float32
+        k_f32[...] = k_buf[slot].astype(jnp.float32)
+        v_f32[...] = v_buf[slot].astype(jnp.float32)
+        for h in range(kvh):
+            own = pl.ds(h, block_t, stride=kvh)   # head h's row of each position
+            k = k_f32[own, :].astype(k_buf.dtype)                  # (T, hd)
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale                                           # (G, T)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            v = v_f32[own, :].astype(v_buf.dtype)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                      # (G, hd)
+            acc_scr[h] = acc_scr[h] * corr + pv
+            m_scr[h] = m_new
+        slot_ref[0] = 1 - slot
+        return carry
+
+    jax.lax.fori_loop(0, n_live, block, 0)
+
+    @pl.when(n_live == 0)
+    def _dead():
+        prefetch_next(0, slot_ref[0])
+
+    l = jnp.maximum(l_scr[...], 1e-37)
+    o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -120,37 +168,54 @@ def paged_attention(
 ) -> jax.Array:
     """Paged decode attention over a shared block pool. Returns (B, H, hd)."""
     B, H, hd = q.shape
-    page_size, KVH = k_pages.shape[1], k_pages.shape[2]
+    P, page_size, KVH, _ = k_pages.shape
+    G = H // KVH
     n_blocks = block_table.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(hd)
+    ppb = pages_per_block(page_size, KVH, hd, k_pages.dtype, n_blocks)
 
     kernel = functools.partial(
         _paged_kernel,
         sm_scale=scale,
         page_size=page_size,
+        pages_per_block=ppb,
         n_blocks=n_blocks,
-        group=H // KVH,
+        n_lanes=B,
     )
-    page_map = functools.partial(_page_index, page_size=page_size)
+    lane = pl.BlockSpec((1, KVH, G, hd), lambda b, bt, sl: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, n_blocks),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, j, bt, sl: (b, 0, 0)),
-            pl.BlockSpec((1, page_size, KVH, hd), page_map),
-            pl.BlockSpec((1, page_size, KVH, hd), page_map),
+            lane,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, j, bt, sl: (b, 0, 0)),
+        out_specs=lane,
         scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, hd), jnp.float32),
+            pltpu.VMEM((2, ppb * page_size * KVH, hd), k_pages.dtype),
+            pltpu.VMEM((2, ppb * page_size * KVH, hd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((ppb * page_size * KVH, hd), jnp.float32),
+            pltpu.VMEM((ppb * page_size * KVH, hd), jnp.float32),
+            pltpu.VMEM((KVH, G, 1), jnp.float32),
+            pltpu.VMEM((KVH, G, 1), jnp.float32),
+            pltpu.VMEM((KVH, G, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    # (page_size, KVH, hd) -> (page_size * KVH, hd) merges a page's positions
+    # with its heads: the same bytes, so XLA makes it a bitcast of the pool
+    rows = (P, page_size * KVH, hd)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        interpret=interpret,
-    )(block_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+        out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
+        # the copies run one block ahead across lanes: the grid is sequential
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        # the TPU interpreter runs the DMAs and their semaphores on the CPU
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="paged_attention",
+    )(block_table.astype(jnp.int32).reshape(-1), seq_lens.astype(jnp.int32),
+      q.reshape(B, KVH, G, hd), k_pages.reshape(rows), v_pages.reshape(rows))
+    return out.reshape(B, H, hd)

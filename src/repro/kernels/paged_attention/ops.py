@@ -4,10 +4,11 @@ Decode attention is inference-only — no custom_vjp, no padding gymnastics:
 the pool/page layout is already block-aligned by construction (the engine
 allocates whole pages), so the wrapper only validates the layout contract
 and dispatches to the kernel. ``interpret=True`` runs the same kernel
-through the Pallas interpreter on CPU (the CI smoke path); backends with
-neither fall back to :func:`paged_attention_ref` at the model layer
-(``models/layers.py``), which is bit-compared against the kernel in
-``tests/test_kernels.py``.
+through the Pallas interpreter on CPU (the tests' path). Where the kernel
+does not fit (``models/layers.py`` ``paged_kernel_fits``: not one TPU
+device, an int8 pool, a head dim other than 128) the model layer runs
+:func:`paged_attention_ref`, which ``tests/test_kernels.py`` compares
+against the kernel.
 """
 from __future__ import annotations
 

@@ -564,6 +564,24 @@ def _paged_attend_gathered(
     return out.reshape(B, H, hd)
 
 
+def paged_kernel_fits(backend: str, pool_dtype, head_dim: int, mesh=None) -> bool:
+    """Whether decode attention over the paged pool runs the Pallas kernel.
+
+    It does on a TPU, over a bf16 or f32 pool with 128-lane rows
+    (``head_dim`` 128) that one device holds whole (``mesh`` None is the
+    default device). Everywhere else the gather (``paged_attention_ref``)
+    serves: on the CPU; for an int8 pool, whose pages the gather
+    dequantizes; for other head dims, whose rows the kernel's per-head
+    strided reads cannot take (the TPU compiler refuses them); and on a mesh
+    of more than one device, which splits the pool over its model axis or
+    copies it, and where a bare ``pallas_call`` (GSPMD does not partition
+    it) would gather it whole.
+    """
+    float_pool = jnp.dtype(pool_dtype) in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+    one_device = mesh is None or mesh.size == 1
+    return backend == "tpu" and float_pool and head_dim == 128 and one_device
+
+
 def decode_attention_paged(
     params: Dict,
     cache: Dict,
@@ -584,12 +602,15 @@ def decode_attention_paged(
     to the reserved trash page and attends over zero positions, producing
     a deterministic output the engine never reads.
 
-    ``use_kernel`` dispatches to the Pallas kernel, which takes bf16/f32
-    pools only: an int8 pool with ``use_kernel=True`` raises. The default
-    is the pure-jnp oracle; int8 pools take the gather path with
-    dequantization scoped to the gathered pages — O(seq_len) dequant per
-    token, unlike the dense ``decode_attention`` path which dequantizes
-    the whole cache each step.
+    ``use_kernel`` dispatches to the Pallas kernel, which reads only each
+    lane's live pages and takes bf16/f32 pools only: an int8 pool with
+    ``use_kernel=True`` raises. ``build_paged_decode_step`` sets it where
+    :func:`paged_kernel_fits` says the kernel fits; tests force it, with
+    ``interpret``, on the CPU. Otherwise the gather path runs, which reads
+    every page of the block table; int8 pools take it with dequantization
+    scoped to the gathered pages — O(max_context) dequant per token, unlike
+    the dense ``decode_attention`` path which dequantizes the whole cache
+    each step.
     """
     from repro.kernels.paged_attention import (
         paged_attention_ref, paged_decode_attention,

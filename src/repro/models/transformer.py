@@ -637,7 +637,7 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig, opts: RunOpts):
 def decode_step_paged(
     params, cache, tokens, seq_lens, block_table,
     cfg: ModelConfig, opts: RunOpts,
-    *, use_kernel: bool = False, interpret: bool = False,
+    *, use_kernel: bool,
 ):
     """One continuous-batching decode step against the paged KV pool.
 
@@ -646,6 +646,9 @@ def decode_step_paged(
     ``decode_step``'s single scalar ``pos``); block_table: (B, max_blocks)
     int32 with -1 for unassigned ranges (a fully dead lane produces
     deterministic garbage logits the engine never samples).
+
+    ``use_kernel`` picks the attention path (``build_paged_decode_step``
+    decides it with ``layers.paged_kernel_fits`` from where the step runs).
 
     Returns (logits (B, 1, V), new cache). DENSE blocks only — see
     ``paged_cache_specs``.
@@ -665,8 +668,7 @@ def decode_step_paged(
         xx = opts.constrain(xx, "activation")
         h = layers.norm(p["ln1"], xx, cfg)
         attn_out, new_c = layers.decode_attention_paged(
-            p["attn"], c, h, seq_lens, block_table, cfg,
-            use_kernel=use_kernel, interpret=interpret,
+            p["attn"], c, h, seq_lens, block_table, cfg, use_kernel=use_kernel,
         )
         xx = xx + attn_out
         h = layers.norm(p["ln2"], xx, cfg)

@@ -62,12 +62,11 @@ class Model:
     def decode_step_paged(
         self, params, cache, tokens, seq_lens, block_table,
         opts: Optional[RunOpts] = None,
-        *, use_kernel: bool = False, interpret: bool = False,
+        *, use_kernel: bool,
     ):
         return transformer.decode_step_paged(
             params, cache, tokens, seq_lens, block_table,
-            self.cfg, opts or RunOpts(),
-            use_kernel=use_kernel, interpret=interpret,
+            self.cfg, opts or RunOpts(), use_kernel=use_kernel,
         )
 
     def paged_cache_specs(self, num_pages: int, page_size: int = 16,

@@ -111,7 +111,10 @@ class DecodeEngine:
 
     One engine per (model, mesh, lane count): the decode step compiles
     once for the static (lanes, max_blocks) shape and every step serves
-    whatever mix of sequences currently occupies the lanes.
+    whatever mix of sequences currently occupies the lanes. Its attention
+    reads only the live pages (the Pallas paged kernel) on one TPU chip
+    over a float pool, and gathers the whole block table elsewhere
+    (``build_paged_decode_step``).
     """
 
     def __init__(
@@ -127,8 +130,6 @@ class DecodeEngine:
         eos_id: Optional[int] = None,
         tracker=None,                       # Optional[ThroughputTracker]
         tracker_key: Any = None,
-        use_kernel: bool = False,
-        interpret: bool = False,
     ):
         from repro.dist import (
             cache_shardings,
@@ -171,10 +172,7 @@ class DecodeEngine:
         repl = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
         self._repl = repl
         self._decode = jax.jit(
-            build_paged_decode_step(
-                model, layout, constrain,
-                use_kernel=use_kernel, interpret=interpret,
-            ),
+            build_paged_decode_step(model, layout, constrain, mesh=mesh),
             in_shardings=(self.param_sh, self._c_sh, repl, repl, repl),
             out_shardings=(None, self._c_sh),
             donate_argnums=(1,),

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.config.base import ShardingLayout, TrainConfig
 from repro.models import zoo
+from repro.models.layers import paged_kernel_fits
 from repro.models.transformer import RunOpts
 from repro.optim import (
     OptState,
@@ -240,8 +241,7 @@ def build_decode_step(model: zoo.Model, layout: ShardingLayout, constrain=None):
 
 
 def build_paged_decode_step(
-    model: zoo.Model, layout: ShardingLayout, constrain=None,
-    *, use_kernel: bool = False, interpret: bool = False,
+    model: zoo.Model, layout: ShardingLayout, constrain=None, *, mesh=None,
 ):
     """Continuous-batching decode step against the paged KV pool.
 
@@ -249,13 +249,20 @@ def build_paged_decode_step(
     (B,nb)) -> (logits, cache). The block table and per-lane lengths are
     small host-side int32 arrays re-fed each step (not donated); the pool
     itself is donation-friendly like the dense cache.
+
+    Attention runs the Pallas paged kernel where ``paged_kernel_fits`` the
+    mesh the step is jitted for (``None``: the default device), its
+    platform, and the pool's dtype and head dim; the gather otherwise.
     """
     opts = run_opts_from_layout(layout, constrain)
+    backend = jax.default_backend() if mesh is None else mesh.devices.flat[0].platform
+    pool_dtype = jnp.int8 if layout.int8_kv_cache else model.cfg.dtype
+    use_kernel = paged_kernel_fits(backend, pool_dtype, model.cfg.resolved_head_dim, mesh)
 
     def paged_decode_step(params, cache, tokens, seq_lens, block_table):
         logits, new_cache = model.decode_step_paged(
             params, cache, tokens, seq_lens, block_table, opts,
-            use_kernel=use_kernel, interpret=interpret,
+            use_kernel=use_kernel,
         )
         return logits, new_cache
 

@@ -153,6 +153,7 @@ def test_mlstm_state_carry_composes():
 # ---------------------------------------------------------------------------
 
 from repro.kernels.paged_attention import paged_attention_ref, paged_decode_attention  # noqa: E402
+from repro.kernels.paged_attention.kernel import pages_per_block  # noqa: E402
 
 
 def _paged_case(B, H, KVH, hd, page_size, max_blocks, lens, dtype, seed=0):
@@ -173,17 +174,31 @@ def _paged_case(B, H, KVH, hd, page_size, max_blocks, lens, dtype, seed=0):
     return q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(np.asarray(lens, np.int32))
 
 
+# lane lengths at the kernel's edges: 0, 1, one page, one compute block,
+# one past it, the whole block table
+EDGES = "edges"
+
 PAGED_CASES = [
     # B, H, KVH, hd, page_size, max_blocks, lens, dtype
     (2, 4, 4, 64, 16, 4, [64, 33], jnp.float32),
     (3, 8, 2, 64, 16, 4, [1, 50, 64], jnp.float32),   # GQA 4:1, len-1 lane
     (2, 4, 1, 32, 8, 6, [41, 17], jnp.float32),       # MQA, ragged pages
     (2, 4, 2, 64, 16, 4, [64, 7], jnp.bfloat16),
+    # qwen3-4b's heads over a 2,048-position table
+    (6, 32, 8, 128, 16, 128, EDGES, jnp.bfloat16),
+    (6, 32, 8, 128, 16, 128, EDGES, jnp.float32),
+    (4, 32, 8, 128, 16, 128, [2048, 3, 700, 129], jnp.bfloat16),
+    # GQA 8:1: smaller pages, so a block of 32 of them
+    (6, 16, 2, 128, 16, 40, EDGES, jnp.bfloat16),
 ]
 
 
 @pytest.mark.parametrize("B,H,KVH,hd,ps,mb,lens,dtype", PAGED_CASES)
 def test_paged_attention_kernel_matches_ref(B, H, KVH, hd, ps, mb, lens, dtype):
+    if lens == EDGES:
+        blk = ps * pages_per_block(ps, KVH, hd, dtype, mb)
+        lens = [0, 1, ps, blk, blk + 1, ps * mb]
+        assert 1 < blk < ps * mb
     q, kp, vp, table, sl = _paged_case(B, H, KVH, hd, ps, mb, lens, dtype)
     out = paged_decode_attention(q, kp, vp, table, sl, interpret=True)
     ref = paged_attention_ref(q, kp, vp, table, sl)

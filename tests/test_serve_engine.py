@@ -280,6 +280,27 @@ def test_paged_int8_pool_refuses_the_kernel():
         )
 
 
+@pytest.mark.parametrize("backend,dtype,head_dim,mesh_shape,kernel", [
+    ("cpu", jnp.bfloat16, 128, (1, 1), False),    # the CPU gathers
+    ("tpu", jnp.int8, 128, (1, 1), False),        # the gather dequantizes int8 pages
+    ("tpu", jnp.bfloat16, 128, (2, 1), False),    # a pool on two devices: copied,
+    ("tpu", jnp.bfloat16, 128, (1, 2), False),    # or split over the model axis
+    ("tpu", jnp.bfloat16, 256, (1, 1), False),    # rows the kernel cannot read
+    ("tpu", jnp.bfloat16, 32, (1, 1), False),
+    ("tpu", jnp.bfloat16, 128, (1, 1), True),     # a float pool on one TPU device
+    ("tpu", jnp.float32, 128, None, True),        # ... or on the default device
+])
+def test_paged_attention_path_follows_backend_dtype_and_placement(
+    backend, dtype, head_dim, mesh_shape, kernel
+):
+    from repro.models.layers import paged_kernel_fits
+
+    mesh = None if mesh_shape is None else jax.sharding.AbstractMesh(
+        mesh_shape, ("data", "model")
+    )
+    assert paged_kernel_fits(backend, dtype, head_dim, mesh) is kernel
+
+
 def test_engine_resumes_a_stream_at_the_context_limit(served):
     """A stream whose prompt + max_new_tokens fills the whole context is
     shed mid-way and resumes on an engine of the same context: its resumed

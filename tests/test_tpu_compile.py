@@ -5,8 +5,10 @@ described (``v5e:2x2``), not attached: the TPU compiler refuses here what
 it would refuse on the chip — block shapes off the tiling, too much VMEM
 — which interpret-mode runs never see. Nothing runs, so these tests say
 nothing about results or times. Kernel shapes are qwen3-4b's: 32 query
-heads, 8 KV heads, head_dim 128; the paged pool is 8 lanes at 2048 context
-in pages of 16, the flash prefill 2048 tokens. The train step is the
+heads, 8 KV heads, head_dim 128 (the paged kernel also at the other head
+shapes it serves); the paged pool is 8 lanes at 2048 context in pages of
+16, the flash prefill 2048 tokens. The engine's whole paged decode step
+compiles at the serving benchmark's shapes. The train step is the
 orchestrator's, on each mesh a 4-chip pool is planned into.
 
 The topology is described inside a fixture, never at import, and the
@@ -60,15 +62,73 @@ def _spec(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-def test_paged_kernel_compiles_at_qwen3_4b_widths(one_chip):
+@pytest.mark.parametrize("heads,kv_heads,dtype", [
+    (H, KVH, jnp.bfloat16),
+    (H, KVH, jnp.float32),
+    (20, 20, jnp.bfloat16),     # qwen1.5-4b: no GQA
+    (48, 8, jnp.bfloat16),      # internvl2-26b: GQA 6:1
+])
+def test_paged_kernel_compiles_at_qwen3_4b_widths(one_chip, heads, kv_heads, dtype):
+    """Every head shape of the zoo's DENSE configurations whose pool the
+    kernel serves (head_dim 128)."""
     blocks = CONTEXT // PAGE
-    pool = _spec(one_chip, (LANES * blocks + 1, PAGE, KVH, HD), jnp.bfloat16)
+    pool = _spec(one_chip, (LANES * blocks + 1, PAGE, kv_heads, HD), dtype)
     compiled = jax.jit(paged_decode_attention).lower(
-        _spec(one_chip, (LANES, H, HD), jnp.bfloat16), pool, pool,
+        _spec(one_chip, (LANES, heads, HD), dtype), pool, pool,
         _spec(one_chip, (LANES, blocks), jnp.int32),
         _spec(one_chip, (LANES,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_decode_step_runs_the_kernel_at_bench_shapes(topo):
+    """The engine's whole paged decode step, jitted as ``DecodeEngine`` jits
+    it, at the serving benchmark's shapes: qwen3-4b tied in bf16, 16 lanes,
+    1,025 pages of 16, a 2,048-position block table, on one chip. The
+    layers' attention is the Pallas kernel; the gather of the whole block
+    table (``bf16[2048,16,8,128]``, 16 lanes x 128 pages) is gone."""
+    import dataclasses
+
+    from repro.config import ShardingLayout, get_arch
+    from repro.dist import (
+        ElasticMeshManager, cache_shardings, make_activation_constrainer, param_shardings,
+    )
+    from repro.models import build_model
+    from repro.models.common import ParamSpec
+    from repro.train.steps import build_paged_decode_step
+
+    lanes, pages = 16, 1025
+    cfg = dataclasses.replace(
+        get_arch("qwen3-4b"), tie_embeddings=True, param_dtype="bfloat16", dtype="bfloat16",
+    )
+    model, layout = build_model(cfg), ShardingLayout()
+    mesh = ElasticMeshManager(devices=topo.devices).plan_for(1).mesh
+    param_sh = param_shardings(model.specs, mesh, layout)
+    cache_specs = model.paged_cache_specs(pages, PAGE)
+    cache_sh = cache_shardings(cache_specs, mesh, layout)
+    repl = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    step = jax.jit(
+        build_paged_decode_step(
+            model, layout, make_activation_constrainer(mesh, layout, cfg), mesh=mesh,
+        ),
+        in_shardings=(param_sh, cache_sh, repl, repl, repl),
+        out_shardings=(None, cache_sh),
+        donate_argnums=(1,),
+    )
+    shaped = lambda tree, sh: jax.tree_util.tree_map(
+        lambda s, d: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=d), tree, sh,
+    )
+    cache = jax.tree_util.tree_map(
+        lambda s, d: jax.ShapeDtypeStruct(s.shape, jnp.dtype(s.dtype), sharding=d),
+        cache_specs, cache_sh, is_leaf=lambda x: isinstance(x, ParamSpec),
+    )
+    lane_spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=repl)
+    text = step.lower(
+        shaped(model.abstract_params(), param_sh), cache, lane_spec((lanes, 1)),
+        lane_spec((lanes,)), lane_spec((lanes, CONTEXT // PAGE)),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "bf16[2048,16,8,128]" not in text
 
 
 def _flash_args(one_chip):
