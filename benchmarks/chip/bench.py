@@ -7,6 +7,23 @@ per-layer metric is a reader of its own (``metrics/<metric>.py``). The
 traffic file names the general driver that runs it (``drivers/<driver>
 .py``). A cell added later brings only such files; nothing here changes.
 
+A configuration names its model family (``"reference"``), and the family
+is one file, ``reference/<family>.py``, found like the others. A new
+architecture brings that file; it defines:
+
+* ``KEYS``: each of the configuration's published sizes, by its source
+  key, mapped to the field of the program's ``ModelConfig`` that holds it
+  (``programs.py`` checks the two against each other);
+* ``program_fields(conf, arch)``: the rest of the program's config, from
+  the file and the registered architecture ``arch`` (nested settings, the
+  dtypes);
+* ``describe(conf)``: the parameter tree as the program stores it, each
+  leaf ``(shape, kind, scale)`` or ``(shape, kind, scale, share)``, where
+  ``share`` is the part of a matrix one token uses (``weights.py``);
+* the plain reference that decides ``correct``: for a served family
+  ``compiled_readings``, for a trained one ``train_readings``, ``compare``
+  and ``flops_per_token_beyond_matrices`` (``counters.py``).
+
 A run:
 
 1. refuses unless JAX reports a TPU and at least the chips the cell asks
@@ -28,6 +45,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -104,12 +122,27 @@ def load_module(path: pathlib.Path):
     return mod
 
 
+@functools.lru_cache(maxsize=None)
+def _family_at(path: pathlib.Path):
+    return load_module(path)
+
+
+def family(conf: Dict[str, Any], base: pathlib.Path = HERE):
+    """The module of the configuration's model family,
+    ``<base>/reference/<family>.py``, imported once per file."""
+    path = pathlib.Path(base).resolve() / "reference" / f"{conf['reference']}.py"
+    if not path.is_file():
+        raise SystemExit(f"bench: {path} is missing")
+    return _family_at(path)
+
+
 @dataclasses.dataclass
 class Cell:
     """Everything a driver needs about one cell, read from files."""
 
     name: str
     chips: int
+    base: pathlib.Path              # the directory the cell's files are read from
     config: Dict[str, Any]
     traffic: Dict[str, Any]
     limits: Dict[str, float]
@@ -128,6 +161,7 @@ def resolve_cell(name: str, spec: Dict[str, Any], base: pathlib.Path = HERE) -> 
     return Cell(
         name=name,
         chips=int(w["chips"]),
+        base=base,
         config=config,
         traffic=load_json("traffic", w["traffic"], base),
         # a cell with no limits yet (pending, never calibrated) compares

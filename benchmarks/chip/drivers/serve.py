@@ -107,12 +107,13 @@ def run(ctx: bench.Context) -> bench.Run:
     from repro.serve import DecodeEngine, Request
 
     conf, tr = ctx.cell.config, ctx.cell.traffic
-    cfg = programs.model_config(conf)
+    cfg = programs.model_config(conf, ctx.cell.base)
     model = build_model(cfg)
     plan = ElasticMeshManager(ctx.devices).plan_for(ctx.cell.chips)
     engine = DecodeEngine(model, ShardingLayout(), plan.mesh, lanes=tr["lanes"],
                           num_pages=tr["pool_pages"], max_context=tr["max_context"])
-    params = weights.make(conf, bench.jax_key(ctx.seed), cfg.param_dtype, engine.param_sh)
+    params = weights.make(conf, bench.jax_key(ctx.seed), cfg.param_dtype, engine.param_sh,
+                          ctx.cell.base)
     if not weights.shapes_match(params, model.abstract_params()):
         raise SystemExit("bench: the program's parameter tree is not the one the "
                          "configuration describes")
@@ -228,7 +229,7 @@ def run(ctx: bench.Context) -> bench.Run:
 
     sample = _sample(finished_rids, served, ctx.seed, tr["check"])
     rows = [(by_rid[rid].prompt, served[rid]) for rid in sample]
-    gap, ctrl = _compare(conf, ctx.seed, rows, tr["max_context"], ctx.control)
+    gap, ctrl = _compare(conf, ctx.cell.base, ctx.seed, rows, tr["max_context"], ctx.control)
     counts.update(checked_rows=len(rows), checked_tokens=sum(len(s) for _, s in rows))
     print(f"bench check rows {len(rows)} tokens {counts['checked_tokens']}",
           file=sys.stderr, flush=True)
@@ -267,7 +268,7 @@ def _sample(rids: List[int], served: Dict[int, List[int]], seed: int,
     return [longest] + [int(r) for r in extra]
 
 
-def _compare(conf, seed: int, rows, width: int, control: bool):
+def _compare(conf, base, seed: int, rows, width: int, control: bool):
     """Widest gap of a served token's logit below the reference's best;
     with ``control``, the same for the token that the control (the
     reference with float8 matmuls, in the program's place) puts first at
@@ -275,10 +276,10 @@ def _compare(conf, seed: int, rows, width: int, control: bool):
     import jax
     import jax.numpy as jnp
 
-    ref = bench.load_module(bench.HERE / "reference" / f"{conf['reference']}.py")
+    ref = bench.family(conf, base)
     if not rows:
         return NO_ROWS, NO_ROWS
-    w = weights.make(conf, bench.jax_key(seed), conf.get("serve_dtype", "bfloat16"))
+    w = weights.make(conf, bench.jax_key(seed), conf.get("serve_dtype", "bfloat16"), base=base)
     with jax.default_matmul_precision("highest"):
         f = ref.compiled_readings(conf)
         fq = ref.compiled_readings(conf, "fp8") if control else None

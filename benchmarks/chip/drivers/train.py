@@ -22,7 +22,7 @@ The window goes on from step 3 with the same object:
   new mesh.
 
 Then the check: peak memory is read, the state is dropped, and the plain
-float32 reference (``reference/xlstm.py``) runs the same three steps from
+float32 reference (``reference/<family>.py``) runs the same three steps from
 the same weights on the same rows.
 """
 from __future__ import annotations
@@ -61,7 +61,7 @@ def run(ctx: bench.Context) -> bench.Run:
     from repro.train.steps import TrainState
 
     conf, tr = ctx.cell.config, ctx.cell.traffic
-    cfg = programs.model_config(conf)
+    cfg = programs.model_config(conf, ctx.cell.base)
     model = build_model(cfg)
     layout = ShardingLayout()          # the orchestrator's layout
     tc = _train_config(tr, ctx.seed)
@@ -75,7 +75,7 @@ def run(ctx: bench.Context) -> bench.Run:
     key = bench.jax_key(ctx.seed)
 
     sh0 = steps[0][1]
-    params = weights.make(conf, key, cfg.param_dtype, sh0.params)
+    params = weights.make(conf, key, cfg.param_dtype, sh0.params, ctx.cell.base)
     if not weights.shapes_match(params, model.abstract_params()):
         raise SystemExit("bench: the program's parameter tree is not the one the "
                          "configuration describes")
@@ -113,7 +113,7 @@ def run(ctx: bench.Context) -> bench.Run:
         if s == 0:
             b1 = tc.beta1
             first_grad = [float(x) / (1 - b1) for x in norms(state.opt.m)]
-    p0 = weights.make(conf, key, cfg.param_dtype, steps[cur][1].params)
+    p0 = weights.make(conf, key, cfg.param_dtype, steps[cur][1].params, ctx.cell.base)
     change = [float(x) for x in change_norms(state.params, p0)]
     del p0
     prog = {"losses": losses, "grad": first_grad, "change": change}
@@ -190,12 +190,12 @@ def _check(conf, tr, key, rows, prog, ctx) -> Dict[str, Any]:
     """The reference's three steps on one chip, compared with the program's."""
     import jax
 
-    ref = bench.load_module(bench.HERE / "reference" / f"{conf['reference']}.py")
+    ref = bench.family(conf, ctx.cell.base)
     dev = ctx.devices[0]
     batches = [rows.batch(s) for s in range(3)]
     with jax.default_device(dev), jax.default_matmul_precision("highest"):
         def p():
-            return weights.make(conf, key, "float32")
+            return weights.make(conf, key, "float32", base=ctx.cell.base)
 
         want = ref.train_readings(conf, tr["optimizer"], p, batches)
         out = ref.compare(prog, want)

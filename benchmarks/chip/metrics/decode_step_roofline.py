@@ -13,6 +13,6 @@ def read(trace, counts, cell):
     pk = counters.peaks(counts["device_kind"])
     t_min = 0.0
     for decoded, context in counts["steps"]:
-        need = counters.decode_step(cell.config, decoded, context)
+        need = counters.decode_step(cell.config, decoded, context, cell.base)
         t_min += max(need["flops"] / pk["bf16_flop_per_s"], need["bytes"] / pk["hbm_bytes_per_s"])
     return 100.0 * t_min / trace.program_seconds(r"paged_decode_step")

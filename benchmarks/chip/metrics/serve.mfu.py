@@ -9,6 +9,7 @@ def read(trace, counts, cell):
     if not counts.get("steps"):
         return None
     pk = counters.peaks(counts["device_kind"])
-    flops = sum(counters.decode_step(cell.config, d, c)["flops"] for d, c in counts["steps"])
-    flops += sum(counters.prefill_flops(cell.config, p) for p in counts["prefill_lens"])
+    flops = sum(counters.decode_step(cell.config, d, c, cell.base)["flops"]
+                for d, c in counts["steps"])
+    flops += sum(counters.prefill_flops(cell.config, p, cell.base) for p in counts["prefill_lens"])
     return 100.0 * flops / (counts["window_s"] * pk["bf16_flop_per_s"])

@@ -8,5 +8,6 @@ def read(trace, counts, cell):
     if not counts.get("tokens"):
         return None
     pk = counters.peaks(counts["device_kind"])
-    rate = counters.train_flops_per_token(cell.config) * counts["tokens"] / counts["window_s"]
+    per_token = counters.train_flops_per_token(cell.config, cell.base)
+    rate = per_token * counts["tokens"] / counts["window_s"]
     return 100.0 * rate / (pk["bf16_flop_per_s"] * counts["chips"])

@@ -1,4 +1,5 @@
-"""Plain float32 Qwen3 (decoder only, tied or untied head), in jax.numpy.
+"""The Qwen3 family: its keys, its parameter tree, and the plain float32
+Qwen3 (decoder only, tied or untied head), in jax.numpy.
 
 Follows Qwen3's published modeling: token embedding; per layer RMSNorm,
 q/k/v projections without bias, RMSNorm of q and k over head_dim (qk-norm),
@@ -27,6 +28,47 @@ import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
+
+# source key -> the program's ModelConfig field
+KEYS = {
+    "hidden_size": "d_model", "num_hidden_layers": "num_layers",
+    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim", "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+}
+
+
+def program_fields(conf: Dict[str, Any], arch) -> Dict[str, Any]:
+    """The program's config fields besides ``KEYS``: it computes in the
+    dtype it serves in."""
+    return {"dtype": conf["serve_dtype"]}
+
+
+def describe(c: Dict[str, Any]) -> Dict[str, Any]:
+    """The parameter tree as the program stores it: (shape, kind, scale)."""
+    d, L = c["hidden_size"], c["num_hidden_layers"]
+    H, KVH, hd = c["num_attention_heads"], c["num_key_value_heads"], c["head_dim"]
+    f, V = c["intermediate_size"], c["vocab_size"]
+    qd, kd = H * hd, KVH * hd
+    tree: Dict[str, Any] = {
+        "embed": ((V, d), "embed", 1.0),
+        "final_norm": {"scale": ((d,), "norm", 1.0)},
+        "blocks": {
+            "ln1": {"scale": ((L, d), "norm", 1.0)},
+            "attn": {
+                "wq": ((L, d, qd), "matrix", 1.0), "wk": ((L, d, kd), "matrix", 1.0),
+                "wv": ((L, d, kd), "matrix", 1.0), "wo": ((L, qd, d), "matrix", 1.0),
+                "q_norm": ((L, hd), "norm", 1.0), "k_norm": ((L, hd), "norm", 1.0),
+            },
+            "ln2": {"scale": ((L, d), "norm", 1.0)},
+            "mlp": {"wi_gate": ((L, d, f), "matrix", 1.0), "wi_up": ((L, d, f), "matrix", 1.0),
+                    "wo": ((L, f, d), "matrix", 1.0)},
+        },
+    }
+    if not c["tie_word_embeddings"]:
+        tree["lm_head"] = ((d, V), "matrix", 1.0)
+    return tree
 
 
 def _fp8(x):

@@ -1,4 +1,6 @@
-"""Plain float32 xLSTM training steps (loss, gradients, AdamW), in jax.numpy.
+"""The xLSTM family: its keys, its parameter tree, its FLOPs beyond the
+matrices, and plain float32 xLSTM training steps (loss, gradients, AdamW),
+in jax.numpy.
 
 The model is the repo's xLSTM stack as its docstrings state it
 (arXiv:2405.04517 with the repo's departures, listed in the configuration
@@ -28,6 +30,7 @@ per-tensor scale, the step below the bfloat16 the program computes in.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Any, Dict, Optional
@@ -38,6 +41,68 @@ import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
+
+# source key -> the program's ModelConfig field
+KEYS = {
+    "embedding_dim": "d_model", "num_blocks": "num_layers", "num_heads": "num_heads",
+    "vocab_size": "vocab_size", "slstm_every": "slstm_every", "norm_eps": "norm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+}
+
+
+def program_fields(conf: Dict[str, Any], arch) -> Dict[str, Any]:
+    """The program's config fields besides ``KEYS``: one key/value head per
+    head, the mLSTM's chunk, float32 master weights and the compute dtype."""
+    return {"num_kv_heads": conf["num_heads"],
+            "ssm": dataclasses.replace(arch.ssm, chunk=conf["mlstm_chunk"]),
+            "param_dtype": conf["param_dtype"], "dtype": conf["compute_dtype"]}
+
+
+def describe(c: Dict[str, Any]) -> Dict[str, Any]:
+    """The parameter tree as the program stores it: (shape, kind, scale)."""
+    d, V, H = c["embedding_dim"], c["vocab_size"], c["num_heads"]
+    per = c["slstm_every"]
+    G, M = c["num_blocks"] // per, per - 1
+    inner = c["mlstm_proj_factor"] * d
+    ff = c["slstm_ffn_factor"] * d
+    return {
+        "embed": ((V, d), "embed", 1.0),
+        "final_norm": {"scale": ((d,), "norm", 1.0)},
+        "lm_head": ((d, V), "matrix", 1.0),
+        "groups": {
+            "mlstm": {
+                "block": {
+                    "up_proj": ((G, M, d, 2 * inner), "matrix", 1.0),
+                    "wq": ((G, M, inner, inner), "matrix", 1.0),
+                    "wk": ((G, M, inner, inner), "matrix", 1.0),
+                    "wv": ((G, M, inner, inner), "matrix", 1.0),
+                    "w_if": ((G, M, inner, 2 * H), "matrix", 1.0),
+                    "b_if": ((G, M, 2 * H), "zeros", 0.0),
+                    "down_proj": ((G, M, inner, d), "matrix", 1.0),
+                },
+                "ln": {"scale": ((G, M, d), "norm", 1.0)},
+            },
+            "slstm": {
+                "block": {
+                    "w_gates": ((G, d, 4 * d), "matrix", 1.0),
+                    "r_gates": ((G, d, 4 * d), "matrix", 0.5),
+                    "b_gates": ((G, 4 * d), "zeros", 0.0),
+                    "up_proj": ((G, d, ff), "matrix", 1.0),
+                    "down_proj": ((G, ff // 2, d), "matrix", 1.0),
+                },
+                "ln": {"scale": ((G, d), "norm", 1.0)},
+            },
+        },
+    }
+
+
+def flops_per_token_beyond_matrices(c: Dict[str, Any]) -> int:
+    """The forward's work per token that no weight matrix counts: the
+    mLSTM's matrix-memory update and readout, 4 * heads * head_dim**2 per
+    mLSTM block."""
+    d, H, per = c["embedding_dim"], c["num_heads"], c["slstm_every"]
+    hd = c["mlstm_proj_factor"] * d // H
+    return 4 * H * hd * hd * (c["num_blocks"] // per * (per - 1))
 
 
 def _fp8(x):

@@ -11,6 +11,8 @@
   window;
 * busy time: the union of the operations' intervals, averaged over the
   chips the cell uses; idle share is one minus busy over the window;
+* an operation's own time (``op_seconds``): its device time net of the
+  operations nested in it, found by program and operation label;
 * idle gaps of the first chip, each labelled by the innermost host span
   (``serve.step``, ``serve.idle``, ``train.segment``, ``reshard``, ...)
   that covers its middle, ``host`` where none does;
@@ -112,6 +114,13 @@ class Trace:
 
     def __post_init__(self):
         self._busy: Dict[int, tuple] = {}
+        self._self: Dict[int, Dict[str, float]] = {}
+
+    def _self_times(self, chip: int) -> Dict[str, float]:
+        """``self_times`` of the chip's operations, computed once."""
+        if chip not in self._self:
+            self._self[chip] = self_times(self.ops.get(chip, []), self.modules.get(chip, []))
+        return self._self[chip]
 
     def _busy_index(self, chip: int) -> tuple:
         """The chip's busy intervals, their starts and ends, and the busy
@@ -150,6 +159,17 @@ class Trace:
         rx = re.compile(pattern)
         per = [sum(e - s for s, e, n in self.modules.get(c, []) if rx.search(n)) * NS
                for c in self.chips]
+        return sum(per) / len(per) if per else 0.0
+
+    def op_seconds(self, program: str, op: str) -> float:
+        """Device seconds (self time) of the operations whose label matches
+        ``op`` inside programs whose name matches ``program``, averaged
+        over chips."""
+        rp, ro = re.compile(program), re.compile(op)
+        per = []
+        for c in self.chips:
+            per.append(sum(v for k, v in self._self_times(c).items()
+                           if rp.search(k.split("/", 1)[0]) and ro.search(k.split("/", 1)[1])))
         return sum(per) / len(per) if per else 0.0
 
     def program_runs(self, pattern: str, chip: Optional[int] = None) -> List[Interval]:
@@ -193,7 +213,7 @@ class Trace:
             return {"device_ops": [], "idle_gaps": []}
         ops: Dict[str, float] = collections.defaultdict(float)
         for c in self.chips:
-            for k, v in self_times(self.ops.get(c, []), self.modules.get(c, [])).items():
+            for k, v in self._self_times(c).items():
                 ops[k] += v / len(self.chips)
         gaps: Dict[str, float] = collections.defaultdict(float)
         for a, b, label in self.idle_gaps():

@@ -104,3 +104,37 @@ def test_idle_time_and_gap_labels_match_a_direct_count(seed):
         covering = [sp for sp in host if sp[0] <= mid <= sp[1]]
         want = min(covering, key=lambda sp: sp[1] - sp[0])[2] if covering else "host"
         assert label == want
+
+
+def test_paged_attention_roofline_reads_the_kernel_inside_the_decode_step():
+    """Two decode steps, each a layer loop that runs the kernel twice (1 ms
+    each) beside a fusion; a ``paged_attention`` call in another program
+    does not count. The least time is the live keys and values over the
+    HBM bandwidth (the FLOPs take 1/60 of it)."""
+    import json
+    import types
+
+    reader = sup.bench.load_module(sup.BENCH / "metrics" / "paged_attention_roofline.py")
+    kernel = "%paged_attention.11 = bf16[16,32,128]{2,1,0} custom-call(bf16[16,32,128] %q)"
+    ops, modules = [], []
+    for k in range(2):
+        t = k * 10 * MS
+        modules.append((t, t + 5 * MS, "jit_paged_decode_step(1)"))
+        ops += [(t, t + 5 * MS, "%while.3 = (s32[]) while(s32[] %t)"),
+                (t + 0.5 * MS, t + 1.5 * MS, kernel),
+                (t + 1.5 * MS, t + 2 * MS, "%f = f32[] fusion()"),
+                (t + 2.5 * MS, t + 3.5 * MS, kernel)]
+    modules.append((30 * MS, 31 * MS, "jit_prefill(2)"))
+    ops.append((30 * MS, 31 * MS, kernel))
+    t = trace.Trace(window=(0, 40 * MS), ops={0: ops}, modules={0: modules}, host=[], chips=[0])
+    assert t.op_seconds("paged_decode_step", "paged_attention.*custom-call") == pytest.approx(4e-3)
+    conf = json.loads((sup.BENCH / "configs" / "qwen3-4b.json").read_text())
+    cell = types.SimpleNamespace(config=conf, base=sup.BENCH)
+    counts = {"steps": [(16, 6800), (16, 7000)], "device_kind": "TPU v5 lite"}
+    kv = 2 * 36 * 8 * 128 * 2
+    want = 100.0 * kv * (6800 + 7000) / 819e9 / 4e-3
+    assert reader.read(t, counts, cell) == pytest.approx(want)
+
+    gather = trace.Trace(window=(0, 40 * MS), ops={0: ops[:1] + ops[2:3]},
+                         modules={0: modules[:1]}, host=[], chips=[0])
+    assert reader.read(gather, counts, cell) is None
